@@ -21,8 +21,10 @@ import dataclasses
 import json
 import math
 import os
+import statistics
 import subprocess
 import sys
+import time
 from typing import List
 
 import jax
@@ -30,9 +32,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import Row, time_fn
+from repro.core import distributed as dist_lib
 from repro.core import plans as plans_lib
 from repro.core import tree as tree_lib
-from repro.core.engine import BSTEngine, PAPER_CONFIGS
+from repro.core.engine import BSTEngine, EngineConfig, PAPER_CONFIGS
 from repro.data.keysets import make_key_sets, make_tree_data
 from repro.serving import BSTServer
 
@@ -158,7 +161,7 @@ def _retired_hyb_driver(tree, n_trees: int, mapping: str, slack: float = 2.0):
         )
         per_q, per_act = plans_lib.gather_phase(queries, dplan)
         sub_v, sub_f = plans_lib.descend_phase(
-            fk, fv, sub_h, per_q, per_act, use_kernel=True, interpret=True
+            fk, fv, sub_h, per_q, per_act, use_kernel=True
         )
         val, found = plans_lib.combine_phase(
             sub_v, sub_f, dplan, B, reg_val, reg_found
@@ -200,7 +203,7 @@ def hyb_kernel_vs_driver_rows(keys, values, batch: int) -> List[Row]:
         )
         ker = jax.jit(
             lambda qq, plan=plan: plans_lib.execute_plan(
-                plan, qq, use_kernel=True, interpret=True
+                plan, qq, use_kernel=True
             )
         )
         drv = _retired_hyb_driver(tree, cfg.n_trees, cfg.mapping)
@@ -275,105 +278,90 @@ def mixed_rw_rows(keys, values, batch: int, rounds: int = 4) -> List[Row]:
     return rows
 
 
-# The sharded serving comparison needs a multi-device host, and the XLA
-# device-count flag must be set before jax initializes -- so the rows are
-# measured in a subprocess (exactly like tests/test_distributed.py) and
-# returned as JSON on the last stdout line.  Device count tracks the
-# PHYSICAL core count: a host-simulated mesh wider than the cores measures
-# oversubscription, not scaling.
-_SHARDED_BENCH = r"""
-import os, sys, json, time, statistics
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(devices)d"
-sys.path.insert(0, %(src)r)
-import numpy as np
-from repro.core.engine import EngineConfig
-from repro.core import distributed as D
-from repro.data.keysets import make_tree_data
-from repro.serving import BSTServer
+def _sharded_rows(devices, chunk, n_chunks, trials, n_keys) -> List[dict]:
+    """Sharded vs single-chip rows over the first ``devices`` JAX devices,
+    measured in THIS process (the body of ``sharded_serve_rows``)."""
+    devs = jax.devices()[:devices]
+    rng = np.random.default_rng(11)
+    keys, values = make_tree_data(n_keys, seed=0)
+    stream = rng.choice(keys, n_chunks * chunk).astype(np.int32)
+    rows = []
 
-DEV = %(devices)d
-CHUNK = %(chunk)d
-N_CHUNKS = %(n_chunks)d
-TRIALS = %(trials)d
-rng = np.random.default_rng(11)
-keys, values = make_tree_data(%(n_keys)d, seed=0)
-stream = rng.choice(keys, N_CHUNKS * CHUNK).astype(np.int32)
-rows = []
+    def drain_stream(srv):
+        srv.submit(stream)
+        t0 = time.perf_counter()
+        srv.drain()
+        return time.perf_counter() - t0
 
-def drain_stream(srv):
-    srv.submit(stream)
+    for strategy in ("dup", "hrz", "hyb"):
+        n_trees = max(2, devices) if strategy != "hrz" else 1
+        cfg = EngineConfig(strategy=strategy, n_trees=n_trees)
+        mesh = dist_lib.make_serving_mesh(strategy, devices=devs)
+        servers = {
+            "single": BSTServer(keys, values, cfg, chunk_size=chunk),
+            "sharded": BSTServer(keys, values, cfg, chunk_size=chunk, mesh=mesh),
+        }
+        for srv in servers.values():
+            srv.warmup(("lookup",))
+        # Interleaved A/B trials so host noise hits both modes alike; the
+        # row records the per-mode MEDIAN drain wall (keys/sec over the
+        # stream).
+        times = {name: [] for name in servers}
+        for _ in range(trials):
+            for name, srv in servers.items():
+                times[name].append(drain_stream(srv))
+        # Per-device stored nodes: the capacity axis subtree sharding buys
+        # (DESIGN.md §9) -- dup replicates (no win), hrz/hyb hold 1/M of
+        # the tree plus the replicated register layer.  MEASURED from each
+        # server's real shard layout, so a sharding regression (an operand
+        # silently replicated) trips the gate instead of a formula hiding it.
+        mem = {name: srv.memory_nodes_per_device() for name, srv in servers.items()}
+        for name in servers:
+            dt = statistics.median(times[name])
+            rows.append({
+                "name": f"serve/sharded_{strategy}/{name}",
+                "us_per_call": dt * 1e6,
+                "derived": ";".join([
+                    f"spair={strategy}",
+                    f"mode={name}",
+                    f"keys_per_sec={stream.size / dt:.3e}",
+                    f"batch={chunk}",
+                    f"devices={devices}",
+                    f"mem_nodes_dev={mem[name]}",
+                ]),
+            })
+
+    # One sharded mixed read/write row: the delta buffer riding the sharded
+    # program as replicated operands, compactions included (DESIGN.md §9).
+    cfg = EngineConfig(strategy="dup", n_trees=max(2, devices), delta_capacity=2048)
+    mesh = dist_lib.make_serving_mesh("dup", devices=devs)
+    srv = BSTServer(keys, values, cfg, chunk_size=chunk, mesh=mesh)
+    srv.warmup(("lookup",))
+    srv.submit_write(np.int32(1), np.int32(1))
+    srv.drain()
+    srv.reset_stats()
+    n_w = chunk // 10
     t0 = time.perf_counter()
-    srv.drain()
-    return time.perf_counter() - t0
-
-for strategy in ("dup", "hrz", "hyb"):
-    n_trees = max(2, DEV) if strategy != "hrz" else 1
-    cfg = EngineConfig(strategy=strategy, n_trees=n_trees)
-    mesh = D.make_serving_mesh(strategy)
-    servers = {
-        "single": BSTServer(keys, values, cfg, chunk_size=CHUNK),
-        "sharded": BSTServer(keys, values, cfg, chunk_size=CHUNK, mesh=mesh),
-    }
-    for srv in servers.values():
-        srv.warmup(("lookup",))
-    # Interleaved A/B trials so host noise hits both modes alike; the row
-    # records the per-mode MEDIAN drain wall (keys/sec over the stream).
-    times = {name: [] for name in servers}
-    for _ in range(TRIALS):
-        for name, srv in servers.items():
-            times[name].append(drain_stream(srv))
-    # Per-device stored nodes: the capacity axis subtree sharding buys
-    # (DESIGN.md §9) -- dup replicates (no win), hrz/hyb hold 1/M of the
-    # tree plus the replicated register layer.  MEASURED from each
-    # server's real shard layout, so a sharding regression (an operand
-    # silently replicated) trips the gate instead of a formula hiding it.
-    mem = {name: srv.memory_nodes_per_device() for name, srv in servers.items()}
-    for name in servers:
-        dt = statistics.median(times[name])
-        rows.append({
-            "name": "serve/sharded_%%s/%%s" %% (strategy, name),
-            "us_per_call": dt * 1e6,
-            "derived": ";".join([
-                "spair=%%s" %% strategy,
-                "mode=%%s" %% name,
-                "keys_per_sec=%%.3e" %% (stream.size / dt),
-                "batch=%%d" %% CHUNK,
-                "devices=%%d" %% DEV,
-                "mem_nodes_dev=%%d" %% mem[name],
-            ]),
-        })
-
-# One sharded mixed read/write row: the delta buffer riding the sharded
-# program as replicated operands, compactions included (DESIGN.md §9).
-cfg = EngineConfig(strategy="dup", n_trees=max(2, DEV), delta_capacity=2048)
-srv = BSTServer(keys, values, cfg, chunk_size=CHUNK, mesh=D.make_serving_mesh("dup"))
-srv.warmup(("lookup",))
-srv.submit_write(np.int32(1), np.int32(1))
-srv.drain()
-srv.reset_stats()
-n_w = CHUNK // 10
-t0 = time.perf_counter()
-for _ in range(4):
-    wk = rng.integers(1, 2**20, n_w).astype(np.int32)
-    srv.submit_write(wk, wk)
-    srv.submit(rng.choice(keys, CHUNK - n_w).astype(np.int32))
-    srv.drain()
-dt = time.perf_counter() - t0
-s = srv.stats
-rows.append({
-    "name": "serve/sharded_mixed_90_10/dup",
-    "us_per_call": dt / 4 * 1e6,
-    "derived": ";".join([
-        "keys_per_sec=%%.3e" %% (s.served / dt),
-        "batch=%%d" %% CHUNK,
-        "devices=%%d" %% DEV,
-        "write_frac=0.10",
-        "updates=%%d" %% s.updates,
-        "compactions=%%d" %% s.compactions,
-    ]),
-})
-print("ROWS_JSON:" + json.dumps(rows))
-"""
+    for _ in range(4):
+        wk = rng.integers(1, 2**20, n_w).astype(np.int32)
+        srv.submit_write(wk, wk)
+        srv.submit(rng.choice(keys, chunk - n_w).astype(np.int32))
+        srv.drain()
+    dt = time.perf_counter() - t0
+    s = srv.stats
+    rows.append({
+        "name": "serve/sharded_mixed_90_10/dup",
+        "us_per_call": dt / 4 * 1e6,
+        "derived": ";".join([
+            f"keys_per_sec={s.served / dt:.3e}",
+            f"batch={chunk}",
+            f"devices={devices}",
+            "write_frac=0.10",
+            f"updates={s.updates}",
+            f"compactions={s.compactions}",
+        ]),
+    })
+    return rows
 
 
 def sharded_serve_rows(
@@ -382,7 +370,7 @@ def sharded_serve_rows(
     trials: int = 7,
     n_keys: int = (1 << 16) - 1,
 ) -> List[Row]:
-    """Sharded vs single-chip serving, same run, forced multi-device host.
+    """Sharded vs single-chip serving, same run.
 
     Two rows per strategy (``serve/sharded_<strategy>/{sharded,single}``,
     tagged ``spair=<strategy>``) plus one sharded mixed read/write row.
@@ -392,22 +380,35 @@ def sharded_serve_rows(
     capacity play) must store strictly fewer nodes per device
     (``mem_nodes_dev``) -- the deterministic figure a host-simulated mesh
     can gate without CPU timing noise.
+
+    On a TPU the rows run in this process over the real chips: this
+    process holds them, so a child could not reach them.  Elsewhere a
+    child process simulates the mesh, because the XLA host device count
+    must be set before JAX starts.  The count tracks the PHYSICAL cores
+    (a host-simulated mesh wider than the cores measures oversubscription,
+    not scaling), and subtree sharding needs a power of two.
     """
+    args = (chunk, n_chunks, trials, n_keys)
+    if jax.default_backend() == "tpu":
+        devices = 1 << int(math.log2(len(jax.devices())))
+        return [Row(**r) for r in _sharded_rows(devices, *args)]
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    # Largest power of two in [2, 8] that fits the cores: subtree sharding
-    # needs a power-of-two mesh axis (and any such count divides the
-    # power-of-two chunk), so a 6-core host measures a 4-device mesh.
     devices = 1 << int(math.log2(max(2, min(8, os.cpu_count() or 2))))
-    code = _SHARDED_BENCH % {
-        "devices": devices,
-        "src": os.path.join(root, "src"),
-        "chunk": chunk,
-        "n_chunks": n_chunks,
-        "trials": trials,
-        "n_keys": n_keys,
-    }
+    code = (
+        "import json, sys\n"
+        f"sys.path[:0] = [{root!r}, {os.path.join(root, 'src')!r}]\n"
+        "from benchmarks.engine_throughput import _sharded_rows\n"
+        f"print('ROWS_JSON:' + json.dumps(_sharded_rows({devices}, *{args!r})))\n"
+    )
+    env = dict(
+        os.environ, XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}"
+    )
     out = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True, timeout=1800
+        [sys.executable, "-c", code],
+        capture_output=True,
+        text=True,
+        timeout=1800,
+        env=env,
     )
     if out.returncode != 0:
         raise RuntimeError(

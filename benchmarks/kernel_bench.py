@@ -1,8 +1,9 @@
-"""Pallas kernel microbenchmarks (interpret mode) vs jnp oracles.
+"""Pallas kernel microbenchmarks vs jnp oracles.
 
-Interpret-mode timings measure the *semantics* executed on CPU, not TPU
-performance; the derived field carries the shapes so real-TPU reruns slot
-into the same harness.
+Off-TPU the BST kernel runs in interpret mode (``pallas_interpret`` rows):
+those timings measure the *semantics* executed on CPU, not TPU
+performance; the derived field carries the shapes so chip runs slot into
+the same harness.
 """
 
 from __future__ import annotations
@@ -26,6 +27,7 @@ def run() -> List[Row]:
     tree = T.build_tree(keys, values)
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.choice(keys, 8192).astype(np.int32))
+    kernel_label = "pallas_interpret" if ops.interpret_mode() else "pallas_mosaic"
     for use_ref in (True, False):
         us = time_fn(
             lambda q: ops.bst_search(
@@ -35,7 +37,7 @@ def run() -> List[Row]:
         )
         rows.append(
             Row(
-                name=f"kernel/bst_search/{'ref' if use_ref else 'pallas_interpret'}",
+                name=f"kernel/bst_search/{'ref' if use_ref else kernel_label}",
                 us_per_call=us,
                 derived=f"keys_per_sec={8192 / (us / 1e6):.3e};tree_nodes={tree.n_nodes}",
             )

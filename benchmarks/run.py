@@ -5,7 +5,7 @@
   fig8  -- memory/utilization vs Hrz (paper Fig. 8)
   fig9  -- timing/energy proxies (paper Fig. 9, modeled; see module doc)
   engine-- real JAX engine throughput (keys/s) for all strategies x query ops
-  kernel-- Pallas kernels (interpret) vs jnp oracles
+  kernel-- Pallas kernels (interpret mode off-TPU) vs jnp oracles
   moe   -- MoE dispatch drop rates: direct vs queue mapping
   roofline -- dry-run-derived three-term roofline per (arch x shape)
 
@@ -65,6 +65,9 @@ def main() -> None:
     ap.add_argument("--json", default=None, help="also write rows to this JSON file")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         engine_throughput,
         fig7_acceleration,

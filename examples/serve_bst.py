@@ -27,6 +27,8 @@ rebuild the sharded programs mid-service.
 
 import os
 
+# Eight simulated devices for the multi-chip sections on a CPU host.  The
+# flag sizes the host platform only: a TPU host keeps its real chips.
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import argparse
@@ -134,6 +136,10 @@ def main():
         f"deleted 1000 (still found {int(dead_f.sum())}), "
         f"{srv.stats.snapshot_swaps} swap(s)"
     )
+
+    if len(jax.devices()) < 8:
+        print(f"\nmulti-device sections skipped: {len(jax.devices())} device(s), need 8")
+        return
 
     # ---- multi-chip: vertical partitioning over the model axis
     print("\ndistributed hybrid engine (8 devices, 2x4 data x model mesh):")

@@ -31,7 +31,8 @@ def runnable_commands(readme: Path) -> list[str]:
         for line in block.splitlines():
             line = line.strip()
             if not line.startswith("PYTHONPATH=src python"):
-                continue  # pip installs etc. are environment setup, not ours
+                continue  # pip installs etc. are environment setup, and
+                # chip_smoke.py needs a TPU: it refuses the CPU by design
             if "pytest" in line or "benchmarks.run" in line:
                 continue  # tier-1 and the benchmark suite run as their own
                 # CI steps (same commands); re-running them here would only

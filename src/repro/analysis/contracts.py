@@ -239,7 +239,6 @@ def check_query_contracts() -> List[Violation]:
                         op,
                         k=_K,
                         use_kernel=use_kernel,
-                        interpret=True,
                     )
                     args = (q, q) if op in plans_lib.RANGE_OPS else (q,)
                     try:

@@ -174,8 +174,9 @@ def dead_modules(repo_root: str) -> Dict[str, str]:
     """module -> classification ('DEAD' | 'TEST_ONLY') for unreachable code.
 
     Roots: the ``launch`` entry points (the CLI surface), plus everything
-    ``benchmarks/``, ``examples/`` and ``scripts/`` import.  ``analysis``
-    is its own root (this tool and CI invoke it directly).
+    ``chip_smoke.py``, ``benchmarks/``, ``examples/`` and ``scripts/``
+    import.  ``analysis`` is its own root (this tool and CI invoke it
+    directly).
     """
     files, edges, strong = build_graph(repo_root)
     weak = {m for m, p in files.items() if p.endswith("__init__.py")}
@@ -184,6 +185,9 @@ def dead_modules(repo_root: str) -> Dict[str, str]:
         (os.path.join(repo_root, d) for d in ("benchmarks", "examples", "scripts")),
         files,
     )
+    smoke = os.path.join(repo_root, "chip_smoke.py")
+    if os.path.isfile(smoke):
+        seeds |= _resolve(_imports_of(smoke), files)
     reachable = _reach(seeds, edges, weak, strong)
     test_seeds = _dir_imports((os.path.join(repo_root, "tests"),), files)
     test_reach = _reach(test_seeds | seeds, edges, set(), strong)

@@ -66,7 +66,6 @@ def serve_gate(
         strategy=strategy,
         n_trees=1 if strategy == "hrz" else n_trees,
         use_kernel=True,
-        interpret=True,
         # High water == capacity and the measured writes stay far below it:
         # no compaction (and no sanctioned compaction sync) in the gate.
         delta_capacity=_DELTA_CAP,

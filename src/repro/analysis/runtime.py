@@ -36,9 +36,9 @@ from typing import Iterator, List
 
 import jax
 
-# Loggers that emit one WARNING record per actual compilation.  The pxla
-# one carries "Compiling <fn> with global shapes and types [...]" for
-# every lowered program (jit and shard_map alike) on jax 0.4.x.
+# Loggers that emit one WARNING record per actual compilation (with
+# ``jax_log_compiles``): "Compiling <fn> ..." for every lowered program,
+# jit and shard_map alike.
 _COMPILE_LOGGERS = (
     "jax._src.interpreters.pxla",
     "jax._src.dispatch",

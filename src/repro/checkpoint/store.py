@@ -33,8 +33,7 @@ _ARRAYS = "arrays.npz"
 
 
 def _flatten_with_paths(tree):
-    # jax.tree.flatten_with_path arrived after 0.4.x; tree_util always has it.
-    flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     paths = ["/".join(str(k) for k in path) for path, _ in flat]
     leaves = [leaf for _, leaf in flat]
     return paths, leaves, treedef

@@ -261,6 +261,10 @@ def select_merged(
     consecutive buffer keys, at tree rank ``j - W_gap``.  Tombstoned and
     overwritten tree keys sit ON gap boundaries, so the strict inequality
     excludes them; overwrites are found through their buffer entry instead.
+    The tree keys of gap ``g`` hold consecutive merged ranks from
+    ``m_g = (tree keys <= its lower boundary) + W_gap``; ``m`` never
+    decreases, so only the last gap with ``m_g <= j`` can hold rank ``j``
+    and each lane reads one tree key, not one per gap.
     ``j``/``valid`` broadcast over any batch shape; returns (keys, values,
     ok) where ``ok`` is False only for masked or out-of-range lanes.
     """
@@ -281,14 +285,16 @@ def select_merged(
     w_gap = jnp.concatenate([zero, w_inc])  # (C+1,) weight prefix per gap
     lo_b = jnp.concatenate([jnp.full((1,), tree_lib.NO_PRED_KEY), delta.keys])
     hi_b = jnp.concatenate([delta.keys, jnp.full((1,), tree_lib.SENTINEL_KEY)])
-    s = jj - w_gap  # candidate tree rank per gap
-    s_ok = (s >= 0) & (s < n_real) & vv
+    first_rank = jnp.minimum(jnp.searchsorted(sorted_keys, lo_b, side="right"), n_real)
+    gap_start = first_rank + w_gap  # (C+1,) merged rank of each gap's first tree key
+    gap = jnp.clip(jnp.sum((gap_start <= jj).astype(jnp.int32), axis=-1) - 1, 0, w_gap.shape[0] - 1)
+    s = j - w_gap[gap]  # candidate tree rank
+    s_ok = (s >= 0) & (s < n_real) & valid
     safe = jnp.clip(s, 0, sorted_keys.shape[0] - 1)
     t_key = sorted_keys[safe]
-    in_gap = s_ok & (t_key > lo_b) & (t_key < hi_b)
-    from_tree = jnp.any(in_gap, axis=-1)
-    t_k = jnp.sum(jnp.where(in_gap, t_key, 0), axis=-1)
-    t_v = jnp.sum(jnp.where(in_gap, sorted_values[safe], 0), axis=-1)
+    from_tree = s_ok & (t_key > lo_b[gap]) & (t_key < hi_b[gap])
+    t_k = jnp.where(from_tree, t_key, 0)
+    t_v = jnp.where(from_tree, sorted_values[safe], 0)
 
     ok = from_delta | from_tree
     key = jnp.where(from_delta, d_key, t_k)

@@ -222,7 +222,6 @@ def make_distributed_query(
     capacity: Optional[int] = None,
     stall_rounds: int = 1,
     use_kernel: bool = False,
-    interpret: bool = True,
     capacity_frac: Optional[float] = None,
 ):
     """Build a jitted distributed ``query(op, ...)`` over ``axis``.
@@ -270,7 +269,6 @@ def make_distributed_query(
             recv_q.reshape(1, -1),
             (recv_live.reshape(-1) != 0)[None, :],
             use_kernel=use_kernel,
-            interpret=interpret,
         )
         packed = plans_lib.pack_ordered(
             plans_lib.OrderedResult(*(f[0].reshape(M, cap) for f in sub))
@@ -373,7 +371,6 @@ def make_distributed_lookup(
     capacity: Optional[int] = None,
     stall_rounds: int = 1,
     use_kernel: bool = False,
-    interpret: bool = True,
 ):
     """Membership shorthand over ``make_distributed_query`` (kept API)."""
     query = make_distributed_query(
@@ -383,7 +380,6 @@ def make_distributed_lookup(
         capacity=capacity,
         stall_rounds=stall_rounds,
         use_kernel=use_kernel,
-        interpret=interpret,
     )
 
     def run(queries: jax.Array):
@@ -401,7 +397,6 @@ def make_dup_query(
     mesh: Mesh,
     axis: str = "data",
     use_kernel: bool = False,
-    interpret: bool = True,
 ):
     """DupN as data parallelism: replicate the tree, shard the query stream.
 
@@ -426,7 +421,6 @@ def make_dup_query(
             tree.height,
             queries[None, :],
             use_kernel=use_kernel,
-            interpret=interpret,
         )
         res = plans_lib.OrderedResult(*(f[0] for f in res))
         if d_ops:
@@ -442,7 +436,6 @@ def make_dup_query(
             tree.height,
             queries[None, :],
             use_kernel=use_kernel,
-            interpret=interpret,
         )
         val, found = val[0], found[0]
         if d_ops:
@@ -519,7 +512,6 @@ def make_sharded_query(
     buffer_slack: float = 2.0,
     stall_rounds: int = 1,
     use_kernel: bool = False,
-    interpret: bool = True,
 ):
     """The serving-facing sharded factory (DESIGN.md §9).
 
@@ -552,7 +544,7 @@ def make_sharded_query(
         )
     if strategy == "dup":
         run = make_dup_query(
-            tree, mesh, axis=axis, use_kernel=use_kernel, interpret=interpret
+            tree, mesh, axis=axis, use_kernel=use_kernel
         )
         run.capacity_frac = None
     else:
@@ -564,7 +556,6 @@ def make_sharded_query(
             capacity_frac=frac,  # hrz: None -> stall-free routing
             stall_rounds=stall_rounds,
             use_kernel=use_kernel,
-            interpret=interpret,
         )
         run.capacity_frac = frac
     run.strategy = strategy

@@ -58,7 +58,6 @@ class EngineConfig:
     # Buffer capacity per subtree as a multiple of the fair share B/n_trees.
     buffer_slack: float = 2.0
     use_kernel: bool = False  # route descent through the Pallas forest kernel
-    interpret: bool = True  # Pallas interpret mode (CPU container)
     # Live write path (DESIGN.md §7): > 0 attaches a delta buffer of that
     # many slots to every query, enabling device-side apply_updates with
     # bulk compaction at the high-water mark.  0 keeps the engine read-only
@@ -175,7 +174,6 @@ class BSTEngine:
                     op,
                     k=k,
                     use_kernel=self.config.use_kernel,
-                    interpret=self.config.interpret,
                 )
             )
             self._query_cache[key] = fn
@@ -206,7 +204,6 @@ class BSTEngine:
             self.plan,
             keys,
             use_kernel=self.config.use_kernel,
-            interpret=self.config.interpret,
         )
         return delta_lib.ingest(
             delta, keys, values, deletes, valid, res.found, res.rank

@@ -286,7 +286,6 @@ def descend_phase(
     *,
     shared_tree: bool = False,
     use_kernel: bool = False,
-    interpret: bool = True,
     delta: Optional[Tuple[jax.Array, ...]] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Forest-batched compare-descend: (n_trees, B) queries in one shot.
@@ -303,7 +302,6 @@ def descend_phase(
         queries,
         height=height,
         active=active,
-        interpret=interpret,
         shared_tree=shared_tree,
         use_ref=not use_kernel,
         delta=delta,
@@ -319,7 +317,6 @@ def descend_phase_ordered(
     *,
     shared_tree: bool = False,
     use_kernel: bool = False,
-    interpret: bool = True,
     delta: Optional[Tuple[jax.Array, ...]] = None,
 ) -> OrderedResult:
     """Ordered forest-batched compare-descend (DESIGN.md §6).
@@ -336,7 +333,6 @@ def descend_phase_ordered(
         queries,
         height=height,
         active=active,
-        interpret=interpret,
         shared_tree=shared_tree,
         use_ref=not use_kernel,
         delta=delta,
@@ -459,16 +455,16 @@ def _hybrid_descend(
     *,
     ordered: bool,
     use_kernel: bool,
-    interpret: bool,
     delta: Optional[Tuple[jax.Array, ...]],
 ) -> Tuple[jax.Array, ...]:
     """Single-chip hyb: the WHOLE pipeline in one call (DESIGN.md §8).
 
-    Register route, queue/direct dispatch, subtree descent, stall-round
-    replay and delta resolution all execute inside the forest
-    ``pallas_call`` (``use_kernel=True``) or its structurally matching jnp
-    oracle -- there is no driver-level composition (and no driver-level
-    delta twin) left to drift.
+    Register route, queue/direct dispatch, subtree descent and stall-round
+    replay all execute inside the forest ``pallas_call``
+    (``use_kernel=True``) or its structurally matching jnp oracle, and
+    ``kernels.ops`` folds the delta buffer in right after -- there is no
+    driver-level composition (and no driver-level delta twin) left to
+    drift.
     """
     chunk = KERNEL_BLOCK_Q if use_kernel else queries.shape[0]
     return kops.bst_hybrid_forest(
@@ -480,7 +476,6 @@ def _hybrid_descend(
         mapping=plan.mapping,
         capacity=hyb_capacity(plan, chunk),
         block_q=KERNEL_BLOCK_Q,
-        interpret=interpret,
         ordered=ordered,
         use_ref=not use_kernel,
         delta=delta,
@@ -492,7 +487,6 @@ def execute_plan_ordered(
     queries: jax.Array,
     *,
     use_kernel: bool = False,
-    interpret: bool = True,
     delta: Optional[delta_lib.DeltaBuffer] = None,
 ) -> OrderedResult:
     """The single-chip driver: one ordered pass through the plan's phases.
@@ -504,9 +498,9 @@ def execute_plan_ordered(
     (DESIGN.md §8).
 
     With ``delta`` (DESIGN.md §7) value/found/rank come back merged
-    against the pending write buffer.  Every strategy resolves the buffer
-    inside the descent call itself -- the driver never composes a jnp
-    twin on top.
+    against the pending write buffer, folded by the descent's own
+    ``kernels.ops`` entry point -- the driver never composes a jnp twin
+    on top.
     """
     B = queries.shape[0]
     d_ops = None if delta is None else delta_lib.operands(delta)
@@ -517,7 +511,6 @@ def execute_plan_ordered(
             plan.forest_height,
             queries[None, :],
             use_kernel=use_kernel,
-            interpret=interpret,
             delta=d_ops,
         )
         return OrderedResult(*(f[0] for f in res))
@@ -534,7 +527,6 @@ def execute_plan_ordered(
             q,
             shared_tree=True,
             use_kernel=use_kernel,
-            interpret=interpret,
             delta=d_ops,
         )
         return OrderedResult(*(f.reshape(-1)[:B] for f in res))
@@ -547,7 +539,6 @@ def execute_plan_ordered(
             queries,
             ordered=True,
             use_kernel=use_kernel,
-            interpret=interpret,
             delta=d_ops,
         )
     )
@@ -558,14 +549,13 @@ def execute_plan(
     queries: jax.Array,
     *,
     use_kernel: bool = False,
-    interpret: bool = True,
     delta: Optional[delta_lib.DeltaBuffer] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Membership lookup through the kernel's 2-output configuration.
 
     Same phase chain as ``execute_plan_ordered`` but none of the ordered
     tracking -- the hot lookup path pays nothing for the §6 datapath.
-    ``delta`` rides the descent call for every strategy (DESIGN.md §7/§8).
+    ``delta`` folds into the descent call for every strategy (DESIGN.md §7).
     """
     B = queries.shape[0]
     d_ops = None if delta is None else delta_lib.operands(delta)
@@ -576,7 +566,6 @@ def execute_plan(
             plan.forest_height,
             queries[None, :],
             use_kernel=use_kernel,
-            interpret=interpret,
             delta=d_ops,
         )
         return val[0], found[0]
@@ -592,7 +581,6 @@ def execute_plan(
             q,
             shared_tree=True,
             use_kernel=use_kernel,
-            interpret=interpret,
             delta=d_ops,
         )
         return val.reshape(-1)[:B], found.reshape(-1)[:B]
@@ -604,7 +592,6 @@ def execute_plan(
         queries,
         ordered=False,
         use_kernel=use_kernel,
-        interpret=interpret,
         delta=d_ops,
     )
     return val, found
@@ -618,7 +605,6 @@ def ordered_query(
     *,
     k: int = 8,
     use_kernel: bool = False,
-    interpret: bool = True,
     delta: Optional[delta_lib.DeltaBuffer] = None,
 ):
     """The per-op query contract (DESIGN.md §6) -- one descent, one epilogue.
@@ -650,7 +636,7 @@ def ordered_query(
     if op == "lookup":
         # The hot membership path: same phases, 2-output kernel config.
         return execute_plan(
-            plan, queries, use_kernel=use_kernel, interpret=interpret, delta=delta
+            plan, queries, use_kernel=use_kernel, delta=delta
         )
 
     if op in RANGE_OPS:
@@ -660,7 +646,6 @@ def ordered_query(
             plan,
             jnp.concatenate([lo, hi]),
             use_kernel=use_kernel,
-            interpret=interpret,
             delta=delta,
         )
         r_lo = OrderedResult(*(f[:B] for f in res))
@@ -682,7 +667,7 @@ def ordered_query(
         )
 
     res = execute_plan_ordered(
-        plan, queries, use_kernel=use_kernel, interpret=interpret, delta=delta
+        plan, queries, use_kernel=use_kernel, delta=delta
     )
     if delta is not None:
         sorted_keys, sorted_values = plan.sorted_view()

@@ -11,8 +11,8 @@
   flash_attention  -- LM substrate hot-spot (32k prefill cells)
 
 Each has a pure-jnp oracle in ref.py and a jitted wrapper in ops.py.
-Kernels are authored for TPU (BlockSpec VMEM tiling) and validated with
-``interpret=True`` on this CPU container.
+The BST wrappers compile their kernel with Mosaic on a TPU and run it in
+the Pallas interpreter on any other backend (``ops.interpret_mode``).
 """
 
 from repro.kernels import ops, ref

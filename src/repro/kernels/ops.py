@@ -1,9 +1,9 @@
 """Jitted public wrappers around the Pallas kernels (the ``ops.py`` contract).
 
-Every op takes ``interpret=`` (True on this CPU container; False compiles the
-Mosaic TPU kernel on real hardware) and falls back to the jnp oracle for
-shapes the kernels do not cover (degenerate sizes), so callers can use these
-unconditionally.
+The BST ops run their Pallas kernel compiled with Mosaic on a TPU and in
+the Pallas interpreter on any other backend (``interpret_mode``), or the
+jnp oracle in ``ref.py`` with ``use_ref=True``.  Nothing falls back
+silently: a kernel that does not compile for the TPU raises.
 """
 
 from __future__ import annotations
@@ -25,16 +25,36 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.queue_dispatch import queue_dispatch_pallas
 
 
+def interpret_mode() -> bool:
+    """Whether the BST kernels run in the Pallas interpreter: on every
+    backend but the TPU, whose compiled Mosaic kernel is the served path."""
+    return jax.default_backend() != "tpu"
+
+
+def _merge_delta(out, delta, queries, active):
+    """Fold the write buffer (DESIGN.md §7) into either path's descent:
+    ``delta-hit > tombstone > tree-hit`` on value/found, merged rank."""
+    if delta is None:
+        return out
+    hit, dead, d_val, wb = ref.bst_delta_resolve_ref(*delta, queries, active)
+    return ref.merge_delta_resolution(out, hit, dead, d_val, wb)
+
+
+def _forest_ref(oracle, forest_keys, forest_values, queries, height, active, shared_tree):
+    """vmap a single-tree oracle over the forest rows (dup shares row 0)."""
+    T = queries.shape[0]
+    if shared_tree:
+        forest_keys = jnp.broadcast_to(forest_keys, (T,) + forest_keys.shape[1:])
+        forest_values = jnp.broadcast_to(forest_values, (T,) + forest_values.shape[1:])
+    if active is None:
+        active = jnp.ones(queries.shape, bool)
+    return jax.vmap(lambda k, v, q, a: oracle(k, v, q, height, a))(
+        forest_keys, forest_values, queries, active
+    )
+
+
 @functools.partial(
-    jax.jit,
-    static_argnames=(
-        "height",
-        "register_levels",
-        "block_q",
-        "interpret",
-        "shared_tree",
-        "use_ref",
-    ),
+    jax.jit, static_argnames=("height", "block_q", "shared_tree", "use_ref")
 )
 def bst_search_forest(
     forest_keys: jax.Array,
@@ -42,9 +62,7 @@ def bst_search_forest(
     queries: jax.Array,
     height: int,
     active: Optional[jax.Array] = None,
-    register_levels: int = 3,
     block_q: int = 512,
-    interpret: bool = True,
     shared_tree: bool = False,
     use_ref: bool = False,
     delta: Optional[Tuple[jax.Array, ...]] = None,
@@ -54,51 +72,30 @@ def bst_search_forest(
     The single entry point behind every engine strategy (DESIGN.md §2): hrz
     is a forest of one, dup shares one tree row across grid rows, hyb gives
     each vertical subtree its own row.  One ``pallas_call`` for all three.
-    ``delta`` optionally rides the write buffer's four flat operands
-    (DESIGN.md §7) on either path; value/found come back merged.
+    ``delta`` optionally folds the write buffer's four flat operands
+    (DESIGN.md §7) into either path; value/found come back merged.
     """
     if use_ref:
-        T = queries.shape[0]
-        fk = forest_keys
-        fv = forest_values
-        if shared_tree:
-            fk = jnp.broadcast_to(fk, (T,) + fk.shape[1:])
-            fv = jnp.broadcast_to(fv, (T,) + fv.shape[1:])
-        if active is None:
-            active = jnp.ones(queries.shape, bool)
-        out = jax.vmap(
-            lambda k, v, q, a: ref.bst_search_ref(k, v, q, height, a)
-        )(fk, fv, queries, active)
-        if delta is not None:
-            hit, dead, d_val, wb = ref.bst_delta_resolve_ref(
-                *delta, queries, active
-            )
-            out = ref.merge_delta_resolution(out, hit, dead, d_val, wb)
-        return out
-    return bst_search_forest_pallas(
-        forest_keys,
-        forest_values,
-        queries,
-        height,
-        active=active,
-        register_levels=register_levels,
-        block_q=block_q,
-        interpret=interpret,
-        shared_tree=shared_tree,
-        delta=delta,
-    )
+        out = _forest_ref(
+            ref.bst_search_ref, forest_keys, forest_values, queries, height,
+            active, shared_tree,
+        )
+    else:
+        out = bst_search_forest_pallas(
+            forest_keys,
+            forest_values,
+            queries,
+            height,
+            active=active,
+            block_q=block_q,
+            interpret=interpret_mode(),
+            shared_tree=shared_tree,
+        )
+    return _merge_delta(out, delta, queries, active)
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=(
-        "height",
-        "register_levels",
-        "block_q",
-        "interpret",
-        "shared_tree",
-        "use_ref",
-    ),
+    jax.jit, static_argnames=("height", "block_q", "shared_tree", "use_ref")
 )
 def bst_ordered_forest(
     forest_keys: jax.Array,
@@ -106,9 +103,7 @@ def bst_ordered_forest(
     queries: jax.Array,
     height: int,
     active: Optional[jax.Array] = None,
-    register_levels: int = 3,
     block_q: int = 512,
-    interpret: bool = True,
     shared_tree: bool = False,
     use_ref: bool = False,
     delta: Optional[Tuple[jax.Array, ...]] = None,
@@ -120,40 +115,27 @@ def bst_ordered_forest(
     The single descent behind every ordered query op (predecessor,
     successor, range_count, range_scan) for every strategy -- same
     forest-batching contract as ``bst_search_forest``, same one
-    ``pallas_call`` lowering.  ``delta`` rides the write buffer (DESIGN.md
+    ``pallas_call`` lowering.  ``delta`` folds the write buffer (DESIGN.md
     §7): value/found/rank come back merged against the pending
     upserts/tombstones; pred/succ stay tree-local (``core/delta.py``).
     """
     if use_ref:
-        T = queries.shape[0]
-        fk = forest_keys
-        fv = forest_values
-        if shared_tree:
-            fk = jnp.broadcast_to(fk, (T,) + fk.shape[1:])
-            fv = jnp.broadcast_to(fv, (T,) + fv.shape[1:])
-        if active is None:
-            active = jnp.ones(queries.shape, bool)
-        out = jax.vmap(
-            lambda k, v, q, a: ref.bst_ordered_ref(k, v, q, height, a)
-        )(fk, fv, queries, active)
-        if delta is not None:
-            hit, dead, d_val, wb = ref.bst_delta_resolve_ref(
-                *delta, queries, active
-            )
-            out = ref.merge_delta_resolution(out, hit, dead, d_val, wb)
-        return out
-    return bst_ordered_forest_pallas(
-        forest_keys,
-        forest_values,
-        queries,
-        height,
-        active=active,
-        register_levels=register_levels,
-        block_q=block_q,
-        interpret=interpret,
-        shared_tree=shared_tree,
-        delta=delta,
-    )
+        out = _forest_ref(
+            ref.bst_ordered_ref, forest_keys, forest_values, queries, height,
+            active, shared_tree,
+        )
+    else:
+        out = bst_ordered_forest_pallas(
+            forest_keys,
+            forest_values,
+            queries,
+            height,
+            active=active,
+            block_q=block_q,
+            interpret=interpret_mode(),
+            shared_tree=shared_tree,
+        )
+    return _merge_delta(out, delta, queries, active)
 
 
 @functools.partial(
@@ -164,7 +146,6 @@ def bst_ordered_forest(
         "mapping",
         "capacity",
         "block_q",
-        "interpret",
         "ordered",
         "use_ref",
     ),
@@ -179,23 +160,22 @@ def bst_hybrid_forest(
     capacity: int = 1,
     active: Optional[jax.Array] = None,
     block_q: int = 512,
-    interpret: bool = True,
     ordered: bool = True,
     use_ref: bool = False,
     delta: Optional[Tuple[jax.Array, ...]] = None,
 ) -> Tuple[jax.Array, ...]:
     """The hybrid strategy's single entry point (DESIGN.md §8): register
-    route, queue/direct dispatch, vertical-subtree descent, stall-round
-    replay and the delta-buffer merge, all in ONE ``pallas_call`` -- or the
-    structurally matching jnp oracle with ``use_ref=True``.  Operands are
-    the (n,) flat FULL tree and a (B,) query batch; outputs are (B,) in the
-    §6 ordered contract (``(values, found)`` with ``ordered=False``).
+    route, queue/direct dispatch, vertical-subtree descent and stall-round
+    replay, all in ONE ``pallas_call`` -- or the structurally matching jnp
+    oracle with ``use_ref=True``.  Operands are the (n,) flat FULL tree and
+    a (B,) query batch; outputs are (B,) in the §6 ordered contract
+    (``(values, found)`` with ``ordered=False``).
 
     ``capacity`` is the per-subtree dispatch-buffer depth per chunk: the
     kernel dispatches each ``block_q`` chunk independently (the FPGA
     streams chunks), the oracle treats the whole batch as one chunk (the
     retired driver's granularity) -- results are identical either way,
-    which is exactly the stall round's contract.  ``delta`` rides the
+    which is exactly the stall round's contract.  ``delta`` folds the
     write buffer on both paths; value/found/rank come back merged.
     """
     if use_ref:
@@ -210,41 +190,31 @@ def bst_hybrid_forest(
             active=active,
             ordered=ordered,
         )
-        if delta is not None:
-            hit, dead, d_val, wb = ref.bst_delta_resolve_ref(
-                *delta, queries, active
-            )
-            out = ref.merge_delta_resolution(out, hit, dead, d_val, wb)
-        return out
-    return bst_hybrid_forest_pallas(
-        tree_keys,
-        tree_values,
-        queries,
-        height,
-        split_level,
-        mapping=mapping,
-        capacity=capacity,
-        active=active,
-        block_q=block_q,
-        interpret=interpret,
-        ordered=ordered,
-        delta=delta,
-    )
+    else:
+        out = bst_hybrid_forest_pallas(
+            tree_keys,
+            tree_values,
+            queries,
+            height,
+            split_level,
+            mapping=mapping,
+            capacity=capacity,
+            active=active,
+            block_q=block_q,
+            interpret=interpret_mode(),
+            ordered=ordered,
+        )
+    return _merge_delta(out, delta, queries, active)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("height", "register_levels", "block_q", "interpret", "use_ref"),
-)
+@functools.partial(jax.jit, static_argnames=("height", "block_q", "use_ref"))
 def bst_search(
     tree_keys: jax.Array,
     tree_values: jax.Array,
     queries: jax.Array,
     height: int,
     active: Optional[jax.Array] = None,
-    register_levels: int = 3,
     block_q: int = 512,
-    interpret: bool = True,
     use_ref: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     if use_ref:
@@ -255,9 +225,8 @@ def bst_search(
         queries,
         height,
         active=active,
-        register_levels=register_levels,
         block_q=block_q,
-        interpret=interpret,
+        interpret=interpret_mode(),
     )
 
 

@@ -4,58 +4,46 @@ LM mode -- batched greedy decoding with prefill + KV cache:
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b --smoke \
       --batch 4 --prompt-len 32 --new-tokens 16
 
-BST mode -- the paper's accelerator served sharded over a host-simulated
-mesh: ``BSTServer(mesh=...)`` routes fixed-shape chunks through the
-strategy's shard_map-lowered plan behind the async double-buffered
-scheduler, with live writes riding the replicated delta buffer:
+BST mode -- the paper's accelerator served through the Pallas forest
+kernel over every device JAX sees.  With more than one device,
+``BSTServer(mesh=...)`` routes fixed-shape chunks through the strategy's
+shard_map-lowered plan behind the async double-buffered scheduler, with
+live writes riding the replicated delta buffer:
   PYTHONPATH=src python -m repro.launch.serve --bst --bst-strategy hyb \
-      --bst-devices 8 --requests 100000 --chunk 8192
+      --requests 100000 --chunk 8192
+``--bst-devices N`` simulates N devices on the CPU backend; the flag it
+sets counts host-platform devices only, so a TPU host serves over its
+real chips either way.
 """
 
 from __future__ import annotations
 
 import argparse
 import os
-import sys
 import time
-
-# The forced host-device count must be set BEFORE jax initializes, and only
-# the BST mode wants it (the LM path keeps the real devices), so the flag
-# is argv-gated ahead of the jax import.
-if "--bst" in sys.argv:
-    _n = 8
-    for _i, _a in enumerate(sys.argv):
-        if _a == "--bst-devices" and _i + 1 < len(sys.argv):
-            _n = int(sys.argv[_i + 1])
-        elif _a.startswith("--bst-devices="):
-            _n = int(_a.split("=", 1)[1])
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={_n}"
-    )
-
-import jax
-import jax.numpy as jnp
 
 
 def bst_main(args) -> None:
-    """Serve a lookup + mixed write stream through the sharded BSTServer."""
+    """Serve a lookup + mixed write stream through the BSTServer."""
+    import jax
     import numpy as np
 
     from repro.core.distributed import make_serving_mesh
     from repro.core.engine import EngineConfig
     from repro.data.keysets import make_tree_data
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serving import BSTServer
 
+    enable_compile_cache()
     strategy = args.bst_strategy
-    mesh = make_serving_mesh(strategy)
-    # The real device count can differ from --bst-devices when the
-    # environment preset XLA_FLAGS (the argv gate never overrides it).
-    n_devices = int(mesh.devices.size)
+    n_devices = len(jax.devices())
+    mesh = make_serving_mesh(strategy) if n_devices > 1 else None
     n_trees = 1 if strategy == "hrz" else max(2, n_devices)
     cfg = EngineConfig(
         strategy=strategy,
         n_trees=n_trees,
         mapping="queue",
+        use_kernel=True,
         delta_capacity=args.chunk // 2,
     )
     keys, values = make_tree_data((1 << 16) - 1, seed=0)
@@ -70,7 +58,7 @@ def bst_main(args) -> None:
     dt = time.time() - t0
     s = srv.stats
     print(
-        f"sharded {strategy} x {n_devices} devices: "
+        f"{strategy} x {n_devices} {jax.devices()[0].platform} device(s): "
         f"{args.requests} lookups in {dt:.2f}s "
         f"({s.keys_per_sec:.0f} keys/s busy, {s.found} found, "
         f"{s.chunks} chunks)"
@@ -99,15 +87,26 @@ def main(argv=None):
     # BST sharded serving mode (DESIGN.md §9)
     ap.add_argument("--bst", action="store_true", help="serve the BST store")
     ap.add_argument("--bst-strategy", default="hyb", choices=("hrz", "dup", "hyb"))
-    ap.add_argument("--bst-devices", type=int, default=8)
+    ap.add_argument(
+        "--bst-devices", type=int, default=None,
+        help="simulate this many devices on the CPU backend",
+    )
     ap.add_argument("--requests", type=int, default=100_000)
     ap.add_argument("--chunk", type=int, default=8_192)
     args = ap.parse_args(argv)
 
+    if args.bst_devices is not None:
+        # Must precede JAX's start-up; it sizes the host platform only.
+        os.environ.setdefault(
+            "XLA_FLAGS", f"--xla_force_host_platform_device_count={args.bst_devices}"
+        )
     if args.bst:
         return bst_main(args)
     if args.arch is None:
         ap.error("--arch is required (or pass --bst for the BST store)")
+
+    import jax
+    import jax.numpy as jnp
 
     from repro.configs import get_config, smoke_config
     from repro.models import model as M

@@ -218,7 +218,6 @@ class BSTServer:
             cfg.strategy,
             buffer_slack=cfg.buffer_slack,
             use_kernel=cfg.use_kernel,
-            interpret=cfg.interpret,
         )
 
     @property

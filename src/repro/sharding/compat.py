@@ -1,10 +1,8 @@
-"""Version-compat shims for the jax sharding APIs this repo leans on.
+"""The two sharding calls this repo makes, with their options pinned once.
 
-The repo targets the ``jax.shard_map`` / ``jax.sharding.AxisType`` surface;
-older jax (e.g. 0.4.x, as in this container) ships ``shard_map`` under
-``jax.experimental`` with the ``check_rep`` spelling and ``jax.make_mesh``
-without ``axis_types``.  Every call site goes through these two helpers so
-the difference lives in exactly one place.
+``shard_map`` programs run unchecked (``check_vma=False``: the serving
+bodies mix replicated and sharded operands on purpose) and meshes use
+``Auto`` axes, so every call site agrees on both.
 """
 
 from __future__ import annotations
@@ -15,24 +13,16 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` when present, else the experimental spelling."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
+    """``jax.shard_map`` with the varying-manual-axes check off by default."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
     )
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """Auto-typed mesh on new jax; plain mesh where AxisType predates."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            axis_shapes,
-            axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
-        )
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    return jax.make_mesh(
+        axis_shapes,
+        axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+    )

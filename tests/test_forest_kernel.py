@@ -99,7 +99,7 @@ def test_single_pallas_call_per_strategy(strategy, n_trees, mapping):
     q = _queries(keys, 256, seed=6)
 
     def run(queries):
-        return plans.execute_plan(plan, queries, use_kernel=True, interpret=True)
+        return plans.execute_plan(plan, queries, use_kernel=True)
 
     jaxpr = jax.make_jaxpr(run)(jnp.asarray(q))
     assert _count_pallas_calls(jaxpr.jaxpr) == 1, (strategy, mapping)
@@ -137,7 +137,7 @@ def test_single_pallas_call_per_ordered_op(op):
         args = (jnp.asarray(q), jnp.asarray(q + 64))
 
     def run(*a):
-        return plans.ordered_query(plan, op, *a, use_kernel=True, interpret=True)
+        return plans.ordered_query(plan, op, *a, use_kernel=True)
 
     jaxpr = jax.make_jaxpr(run)(*args)
     assert _count_pallas_calls(jaxpr.jaxpr) == 1, op

@@ -25,16 +25,16 @@ def test_bst_search_shape_sweep(n_keys, n_queries):
     np.testing.assert_array_equal(np.asarray(f1), np.asarray(f2))
 
 
-@pytest.mark.parametrize("register_levels", [1, 2, 5])
-@pytest.mark.parametrize("block_q", [32, 512])
-def test_bst_search_config_sweep(register_levels, block_q, medium_tree):
+@pytest.mark.parametrize("active_share", [0.0, 0.5, 1.0])
+@pytest.mark.parametrize("block_q", [128, 512])
+def test_bst_search_config_sweep(active_share, block_q, medium_tree):
     tree, keys, _ = medium_tree
     rng = np.random.default_rng(0)
     q = rng.choice(np.concatenate([keys, keys + 1]), size=333).astype(np.int32)
-    act = jnp.asarray(rng.integers(0, 2, size=333).astype(bool))
+    act = jnp.asarray(rng.random(333) < active_share)
     v1, f1 = ops.bst_search(
         tree.keys, tree.values, jnp.asarray(q), height=tree.height,
-        active=act, register_levels=register_levels, block_q=block_q,
+        active=act, block_q=block_q,
     )
     v2, f2 = ref.bst_search_ref(tree.keys, tree.values, jnp.asarray(q), tree.height, act)
     np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))

@@ -57,10 +57,7 @@ def test_analytic_flops_matches_unrolled_hlo(arch):
         jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params),
         tokens, labels,
     ).compile()
-    cost_analysis = compiled.cost_analysis()
-    if isinstance(cost_analysis, (list, tuple)):  # jax<=0.4.x: one dict/device
-        cost_analysis = cost_analysis[0]
-    hlo_flops = cost_analysis["flops"]
+    hlo_flops = compiled.cost_analysis()["flops"]
 
     cost = cell_cost(cfg, shape)
     # analytic counts fwd+2x bwd matmuls only (remat off); HLO adds
