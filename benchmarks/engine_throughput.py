@@ -269,7 +269,7 @@ def mixed_rw_rows(keys, values, batch: int, rounds: int = 4) -> List[Row]:
                     name=f"serve/mixed_{mix}/{name}",
                     us_per_call=s.busy_s / rounds * 1e6,  # one mixed round
                     derived=(
-                        f"keys_per_sec={s.keys_per_sec:.3e};batch={batch};"
+                        f"keys_per_sec={s.served / s.busy_s:.3e};batch={batch};"
                         f"write_frac={write_frac};updates={s.updates};"
                         f"compactions={s.compactions}"
                     ),

@@ -5,10 +5,11 @@
 This is the paper-kind end-to-end scenario (a throughput accelerator): a
 request stream is submitted to ``serving.BSTServer``, which packs it into
 fixed-shape chunks, dispatches them through the engine configured with each
-of the paper's strategies, and accounts achieved keys/second (found counts
-accumulated per chunk).  An ordered-workload mix (predecessor / range_count
-/ range_scan request kinds, DESIGN.md §6) exercises the typed-request
-scheduler with per-op accounting.  A LIVE mixed read/write stream
+of the paper's strategies, and reports keys answered per second of wall
+time (found counts accumulated per chunk) and where the host time went.
+An ordered-workload mix (predecessor / range_count / range_scan request
+kinds, DESIGN.md §6) exercises the typed-request scheduler with per-op
+accounting.  A LIVE mixed read/write stream
 (``--write-rate``) then runs through the delta write path (DESIGN.md §7):
 upserts and deletes land in the engine's device-side buffer via
 ``submit_write`` / ``submit_delete`` in submission order, and compaction
@@ -71,11 +72,13 @@ def main():
     for name, cfg in PAPER_CONFIGS.items():
         srv = BSTServer(keys, values, cfg, chunk_size=args.chunk)
         srv.warmup()
+        t0 = time.perf_counter()
         srv.submit(stream)
         srv.drain()
+        dt = time.perf_counter() - t0
         s = srv.stats
         print(
-            f"{name:8s} {s.keys_per_sec:12.0f} {s.found:10d} "
+            f"{name:8s} {s.served / dt:12.0f} {s.found:10d} "
             f"{srv.memory_nodes():14d}"
         )
 
@@ -91,9 +94,11 @@ def main():
     srv.submit_range(lo, hi, op="range_scan")
     srv.drain()
     print("\nordered workload mix (Hyb8q):")
-    print(f"{'op':12s} {'served':>10s} {'chunks':>7s} {'keys/s':>12s}")
+    print(f"{'op':12s} {'served':>10s} {'chunks':>7s} {'busy ms':>10s}")
     for op, st in srv.stats.per_op.items():
-        print(f"{op:12s} {st.served:10d} {st.chunks:7d} {st.keys_per_sec:12.0f}")
+        print(f"{op:12s} {st.served:10d} {st.chunks:7d} {st.busy_s * 1e3:10.1f}")
+    phases = srv.stats.phase_s.items()
+    print("host ms by phase: " + ", ".join(f"{k} {v * 1e3:.1f}" for k, v in phases))
 
     # ---- live write path: delta-buffered updates, compaction, no rebuilds
     cfg = dataclasses.replace(PAPER_CONFIGS["Hyb8q"], delta_capacity=4096)
@@ -118,6 +123,8 @@ def main():
         f"{s.served / dt:.0f} keys/s end-to-end, {s.updates} updates absorbed "
         f"on device, {s.compactions} compaction(s), 0 rebuilds"
     )
+    phases = s.phase_s.items()
+    print("  host ms by phase: " + ", ".join(f"{k} {v * 1e3:.1f}" for k, v in phases))
     v, f = srv.lookup(wk[half + 1 : half + 9])
     print(f"  post-write lookups: found {int(np.asarray(f).sum())}/8 fresh keys")
 
@@ -183,10 +190,12 @@ def main():
             mesh=make_serving_mesh(strategy),
         )
         srv.warmup()
+        t0 = time.perf_counter()
         srv.submit(srv_stream)
         srv.drain()
+        dt = time.perf_counter() - t0
         s = srv.stats
-        print(f"{strategy:10s} {s.keys_per_sec:12.0f} {s.chunks:7d} {s.found:10d}")
+        print(f"{strategy:10s} {s.served / dt:12.0f} {s.chunks:7d} {s.found:10d}")
 
     # live writes through the sharded hybrid server: the delta buffer rides
     # every sharded read as replicated operands, folded on-device

@@ -1,6 +1,6 @@
 """Runtime-assisted retrace/transfer detection (DESIGN.md §10).
 
-Two instruments, both cheap enough to wrap real serving code:
+Three instruments, all cheap enough to wrap real serving code:
 
   * ``compile_watch()`` -- compile-cache instrumentation: flips
     ``jax_log_compiles`` and captures the "Compiling <name> ..." records
@@ -21,6 +21,9 @@ Two instruments, both cheap enough to wrap real serving code:
     pulled outside ``device_fetch`` is a lint violation (ANA005); on a
     real TPU backend the same ``transfer_guard`` wiring additionally
     raises on it at runtime.
+  * ``gc_watch()`` -- the interpreter's garbage-collection pauses: counts
+    and seconds per generation, each pause a ``gc.gen<N>`` profiler
+    annotation, so a trace names the host gaps the collector causes.
 
 ``device_fetch`` lives here -- importable by ``core``/``serving`` without
 cycles (this module depends only on jax + stdlib).
@@ -30,9 +33,11 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import gc
 import logging
 import threading
-from typing import Iterator, List
+import time
+from typing import Dict, Iterator, List
 
 import jax
 
@@ -163,3 +168,46 @@ def transfer_watch() -> Iterator[TransferWatch]:
     with jax.transfer_guard_host_to_device("disallow"), \
             jax.transfer_guard_device_to_host("disallow"):
         yield watch
+
+
+@dataclasses.dataclass
+class GCWatch:
+    """Collections seen inside a ``gc_watch``, per generation."""
+
+    collections: Dict[int, int] = dataclasses.field(default_factory=dict)
+    seconds: Dict[int, float] = dataclasses.field(default_factory=dict)
+
+    @property
+    def total_s(self) -> float:
+        return sum(self.seconds.values())
+
+
+@contextlib.contextmanager
+def gc_watch() -> Iterator[GCWatch]:
+    """Count and time every garbage collection inside the block.
+
+    A ``gc.callbacks`` hook, registered for the block only, times each
+    collection and wraps it in a ``gc.gen<N>`` ``TraceAnnotation``, which
+    records only while a profiler trace is active.
+    """
+    watch = GCWatch()
+    started = []  # (t0, annotation) of the collection under way
+
+    def hook(phase: str, info: dict) -> None:
+        gen = info["generation"]
+        if phase == "start":
+            annotation = jax.profiler.TraceAnnotation(f"gc.gen{gen}")
+            annotation.__enter__()
+            started.append((time.perf_counter(), annotation))
+        elif started:  # a "stop" whose "start" came before the block is dropped
+            t0, annotation = started.pop()
+            dt = time.perf_counter() - t0
+            annotation.__exit__(None, None, None)
+            watch.collections[gen] = watch.collections.get(gen, 0) + 1
+            watch.seconds[gen] = watch.seconds.get(gen, 0.0) + dt
+
+    gc.callbacks.append(hook)
+    try:
+        yield watch
+    finally:
+        gc.callbacks.remove(hook)

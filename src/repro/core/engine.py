@@ -31,6 +31,7 @@ and the same kernel; ``lookup()`` remains as the membership shorthand.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Dict, Optional, Tuple
@@ -100,6 +101,10 @@ PAPER_CONFIGS = {
 }
 
 
+def _no_span(name: str) -> contextlib.AbstractContextManager:
+    return contextlib.nullcontext()
+
+
 class BSTEngine:
     """Build once, look up batches of keys many times."""
 
@@ -146,6 +151,10 @@ class BSTEngine:
         # read.  Called with the fresh TreeData after EVERY snapshot swap;
         # None by default.
         self.on_snapshot = getattr(self, "on_snapshot", None)
+        # Span hook: ``span("compact")`` wraps each compaction; the serving
+        # layer installs its own, so compaction time lands in its phase
+        # counters and the profiler trace.  No span by default.
+        self.span = getattr(self, "span", _no_span)
 
     # ------------------------------------------------------------------ query
     def query(self, op: str, queries, queries_hi=None, *, k: int = 8):
@@ -319,11 +328,12 @@ class BSTEngine:
         """
         if self.delta is None or self._pending_writes == 0:
             return self.tree
-        self.tree = delta_lib.compact(self.tree, self.delta)
-        self.compactions += 1
-        self._finalize()
-        if self.on_snapshot is not None:
-            self.on_snapshot(self.tree)
+        with self.span("compact"):
+            self.tree = delta_lib.compact(self.tree, self.delta)
+            self.compactions += 1
+            self._finalize()
+            if self.on_snapshot is not None:
+                self.on_snapshot(self.tree)
         return self.tree
 
     def pending_writes(self) -> int:

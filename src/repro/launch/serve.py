@@ -52,17 +52,17 @@ def bst_main(args) -> None:
     rng = np.random.default_rng(1)
     stream = rng.choice(keys, args.requests).astype(np.int32)
 
-    t0 = time.time()
+    t0 = time.perf_counter()
     srv.submit(stream)
     srv.drain()
-    dt = time.time() - t0
+    dt = time.perf_counter() - t0
     s = srv.stats
     print(
         f"{strategy} x {n_devices} {jax.devices()[0].platform} device(s): "
         f"{args.requests} lookups in {dt:.2f}s "
-        f"({s.keys_per_sec:.0f} keys/s busy, {s.found} found, "
-        f"{s.chunks} chunks)"
+        f"({s.served / dt:.0f} ops/s, {s.found} found, {s.chunks} chunks)"
     )
+    print("host ms by phase: " + ", ".join(f"{k} {v * 1e3:.1f}" for k, v in s.phase_s.items()))
 
     # a mixed tail: writes ride the replicated delta buffer on-device
     wk = rng.integers(1, 2**20, args.chunk).astype(np.int32)
@@ -75,6 +75,8 @@ def bst_main(args) -> None:
         f"{int(np.asarray(f).sum())}/16 fresh keys found, "
         f"{srv.stats.compactions} compaction(s)"
     )
+    phases = srv.stats.phase_s.items()
+    print("host ms by phase, all drains: " + ", ".join(f"{k} {v * 1e3:.1f}" for k, v in phases))
 
 
 def main(argv=None):

@@ -32,13 +32,18 @@ atomically between chunks.  This module is that loop, TPU-native:
     bulk insert/delete and installs a new engine.  Lookups submitted before
     the swap but not yet drained see the new state (drain-before-swap if
     read-your-epoch consistency is required);
-  * **keys/sec accounting** -- per-chunk timing with ``block_until_ready``,
+  * **busy accounting** -- per-chunk timing with ``block_until_ready``,
     found counts accumulated per chunk (not just the final one).  Busy
     seconds are attributed per op by the engine lanes each request
     actually occupied (one per point/write/delete key, two per range
     request -- the lo||hi concatenated descent), so mixed spans cannot
-    skew one op's ``keys_per_sec`` with another op's time;
-    ``lanes_per_sec`` is the figure comparable across op mixes;
+    skew one op's busy time with another op's;
+  * **phase spans** -- each drain is split into named host spans (drain,
+    pack, dispatch, sync, fetch, unpack, ingest, compact, rewarm).  One
+    helper feeds two outlets: the seconds land in ``ServerStats.phase_s``
+    (always on), and each span is a ``bst.<name>`` profiler annotation, so
+    a profiler trace shows it on the device trace's clock.  Spans are per
+    drain and per chunk, never per request;
   * **sharded mode** (DESIGN.md §9) -- construct with ``mesh=`` and every
     read chunk routes through the strategy's shard_map-lowered plan
     (``core.distributed.make_sharded_query``: hrz shards the tree by
@@ -94,17 +99,8 @@ class OpStats:
     # one per key for point/write/delete ops, TWO per range request -- the
     # lo and hi bounds both descend (the lo||hi concatenated pass,
     # DESIGN.md §6).  Busy seconds in shared spans are attributed by this
-    # number, and lanes_per_sec is the throughput figure comparable across
-    # op mixes (keys_per_sec counts range requests as one unit each).
+    # number.
     lanes: int = 0
-
-    @property
-    def keys_per_sec(self) -> float:
-        return self.served / self.busy_s if self.busy_s > 0 else 0.0
-
-    @property
-    def lanes_per_sec(self) -> float:
-        return self.lanes / self.busy_s if self.busy_s > 0 else 0.0
 
 
 @dataclasses.dataclass
@@ -122,14 +118,14 @@ class ServerStats:
     updates: int = 0  # write/delete ops absorbed by the delta buffer
     compactions: int = 0  # delta-buffer merges into fresh snapshots
     per_op: Dict[str, OpStats] = dataclasses.field(default_factory=dict)
-
-    @property
-    def keys_per_sec(self) -> float:
-        return self.served / self.busy_s if self.busy_s > 0 else 0.0
-
-    @property
-    def lanes_per_sec(self) -> float:
-        return self.lanes / self.busy_s if self.busy_s > 0 else 0.0
+    # Host seconds per span name (see ``_Span``): each span's own time, a
+    # nested span's time counting for the nested span only, so the values
+    # add up to the wall time the spans cover.
+    phase_s: Dict[str, float] = dataclasses.field(default_factory=dict)
+    drains: int = 0  # drain() calls that served a request
+    # Summed over drains: the drain's start minus the enqueue time of its
+    # oldest request.
+    queue_wait_s: float = 0.0
 
     def op(self, name: str) -> OpStats:
         return self.per_op.setdefault(name, OpStats())
@@ -141,6 +137,39 @@ class _Request:
     op: str
     a: np.ndarray  # keys (point / write / delete ops) / range lows
     b: Optional[np.ndarray]  # range highs (range ops) / write values
+
+
+class _Span:
+    """One named phase of the server's host work.
+
+    On exit its host seconds are added to ``ServerStats.phase_s[name]``,
+    less the time of any span opened inside it; around the block it is a
+    ``jax.profiler.TraceAnnotation`` named ``bst.<name>`` with ``ids`` as
+    its arguments, which records only while a profiler trace is active.
+    """
+
+    __slots__ = ("_server", "_name", "_annotation", "_t0", "_inner")
+
+    def __init__(self, server: "BSTServer", name: str, ids: dict):
+        self._server = server
+        self._name = name
+        self._annotation = jax.profiler.TraceAnnotation("bst." + name, **ids)
+        self._inner = 0.0  # seconds of the spans opened inside this one
+
+    def __enter__(self) -> None:
+        self._annotation.__enter__()
+        self._server._open_spans.append(self)
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter() - self._t0
+        stack = self._server._open_spans
+        stack.pop()
+        if stack:
+            stack[-1]._inner += dt
+        phase_s = self._server.stats.phase_s
+        phase_s[self._name] = phase_s.get(self._name, 0.0) + dt - self._inner
+        self._annotation.__exit__(*exc)
 
 
 class BSTServer:
@@ -183,8 +212,10 @@ class BSTServer:
             # same bound statically, so neither side can drift (DESIGN.md §10).
             invariants.check_chunk_divides(chunk_size, mesh.shape[axis], axis)
         self.stats = ServerStats()
+        self._open_spans: List[_Span] = []
         self._pending: List[_Request] = []
         self._pending_keys = 0
+        self._oldest_t = 0.0  # enqueue time of the oldest pending request
         self._next_ticket = 0
         self._warm_ops: Tuple[str, ...] = ()
         # Fixed jit shape for delta-buffer write chunks (DESIGN.md §7): one
@@ -199,16 +230,26 @@ class BSTServer:
     # --------------------------------------------------------------- snapshot
     def _install(self, tree: TreeData) -> None:
         self._engine = BSTEngine.from_tree(tree, self.config)
+        self._engine.span = self._span  # compaction time lands in phase_s
         if self.mesh is not None:
             self._install_sharded(tree)
             # Compaction can swap the snapshot deep inside apply_ops'
             # chunk loop; the hook rebuilds the sharded programs before
             # any later read can see the stale tree (DESIGN.md §9).
             self._engine.on_snapshot = self._install_sharded
+        # The fresh engine's jit closes over the new snapshot; re-warm so
+        # post-swap chunks (and busy accounting) stay compile-free.
+        self._rewarm()
+
+    def _rewarm(self) -> None:
+        """Re-warm the warmed ops after a snapshot swap."""
         if self._warm_ops:
-            # The fresh engine's jit closes over the new snapshot; re-warm so
-            # post-swap chunks (and keys/sec accounting) stay compile-free.
-            self.warmup(self._warm_ops)
+            with self._span("rewarm"):
+                self.warmup(self._warm_ops)
+
+    def _span(self, name: str, **ids) -> _Span:
+        """A ``_Span`` of this server: ``with self._span("pack"): ...``."""
+        return _Span(self, name, ids)
 
     def _install_sharded(self, tree: TreeData) -> None:
         cfg = self.config
@@ -284,8 +325,8 @@ class BSTServer:
             self._engine.apply_updates(insert_keys, insert_values, delete_keys)
             self.stats.updates += n_ops
             self.stats.compactions += self._engine.compactions - before
-            if self._engine.compactions != before and self._warm_ops:
-                self.warmup(self._warm_ops)  # compaction reset the jit cache
+            if self._engine.compactions != before:
+                self._rewarm()  # compaction reset the jit cache
             return self._engine.tree
         tree = self._engine.tree
         if delete_keys is not None and len(np.atleast_1d(delete_keys)):
@@ -361,6 +402,8 @@ class BSTServer:
             )
 
     def _enqueue(self, req: _Request, size: int) -> int:
+        if not self._pending:
+            self._oldest_t = time.perf_counter()  # once per drain, not per request
         req.ticket = self._next_ticket
         self._next_ticket += 1
         self._pending.append(req)
@@ -395,41 +438,54 @@ class BSTServer:
         if not self._pending:
             return {}
         batch = self._pending
+        keys = self._pending_keys
         self._pending = []
         self._pending_keys = 0
+        self.stats.drains += 1
+        self.stats.queue_wait_s += time.perf_counter() - self._oldest_t
 
         out: Dict[int, tuple] = {}
         span: List[_Request] = []
-        for req in batch:
-            if req.op in WRITE_OPS:
-                if span and span[-1].op not in WRITE_OPS:
-                    self._serve_read_span(span, out)
+        # The ids link every request, by ticket, to the drain that served it.
+        with self._span("drain", first_ticket=batch[0].ticket, requests=len(batch), keys=keys):
+            for req in batch:
+                if req.op in WRITE_OPS:
+                    if span and span[-1].op not in WRITE_OPS:
+                        self._serve_read_span(span, out)
+                        span = []
+                elif span and span[-1].op in WRITE_OPS:
+                    self._serve_write_span(span, out)
                     span = []
-            elif span and span[-1].op in WRITE_OPS:
-                self._serve_write_span(span, out)
-                span = []
-            span.append(req)
-        if span:
-            if span[-1].op in WRITE_OPS:
-                self._serve_write_span(span, out)
-            else:
-                self._serve_read_span(span, out)
+                span.append(req)
+            if span:
+                if span[-1].op in WRITE_OPS:
+                    self._serve_write_span(span, out)
+                else:
+                    self._serve_read_span(span, out)
+            # Freeing the served requests costs about as much as packing
+            # them: free them inside the span, which then holds all of it.
+            del batch, span
         return out
 
     def _serve_read_span(self, reqs: List[_Request], out: Dict[int, tuple]):
         """One writeless span: requests commute, so pack per op kind."""
-        by_op: Dict[str, List[_Request]] = {}
-        for req in reqs:
-            by_op.setdefault(req.op, []).append(req)
-        for op, group in by_op.items():
-            a = np.concatenate([r.a for r in group])
-            b = np.concatenate([r.b for r in group]) if op in RANGE_OPS else None
+        with self._span("pack"):
+            by_op: Dict[str, List[_Request]] = {}
+            for req in reqs:
+                by_op.setdefault(req.op, []).append(req)
+            streams = [
+                (op, group, np.concatenate([r.a for r in group]),
+                 np.concatenate([r.b for r in group]) if op in RANGE_OPS else None)
+                for op, group in by_op.items()
+            ]
+        for op, group, a, b in streams:
             columns = self._serve_stream(op, a, b)
-            lo = 0
-            for r in group:
-                hi = lo + r.a.size
-                out[r.ticket] = tuple(col[lo:hi] for col in columns)
-                lo = hi
+            with self._span("unpack"):
+                lo = 0
+                for r in group:
+                    hi = lo + r.a.size
+                    out[r.ticket] = tuple(col[lo:hi] for col in columns)
+                    lo = hi
 
     def _serve_write_span(self, reqs: List[_Request], out: Dict[int, tuple]):
         """One run of consecutive write/delete requests -> delta ingest.
@@ -455,18 +511,19 @@ class BSTServer:
             values = np.pad(values, (0, pad))
             deletes = np.pad(deletes, (0, pad))
         before = self._engine.compactions
+        n_calls = keys.size // self._write_chunk
         t0 = time.perf_counter()
-        # One engine call per _write_chunk slice: every ingest reuses the
-        # single compiled program regardless of span size (the engine only
-        # re-slices by its own capacity, which may be larger).
-        n_calls = 0
-        for lo in range(0, keys.size, self._write_chunk):
-            sl = slice(lo, lo + self._write_chunk)
-            self._engine.apply_ops(keys[sl], values[sl], deletes[sl], valid[sl])
-            n_calls += 1
-        # dispatch is async: sync on the buffer so busy_s measures the
-        # ingest compute, exactly as _serve_stream syncs on query results
-        jax.block_until_ready(self._engine.delta)
+        # A compaction inside apply_ops is a ``compact`` span of its own.
+        with self._span("ingest", chunks=n_calls):
+            # One engine call per _write_chunk slice: every ingest reuses the
+            # single compiled program regardless of span size (the engine
+            # only re-slices by its own capacity, which may be larger).
+            for lo in range(0, keys.size, self._write_chunk):
+                sl = slice(lo, lo + self._write_chunk)
+                self._engine.apply_ops(keys[sl], values[sl], deletes[sl], valid[sl])
+            # dispatch is async: sync on the buffer so busy_s measures the
+            # ingest compute, exactly as _serve_stream syncs on query results
+            jax.block_until_ready(self._engine.delta)
         dt = time.perf_counter() - t0
         n = int(valid.sum())
         self.stats.busy_s += dt
@@ -475,8 +532,8 @@ class BSTServer:
         self.stats.chunks += n_calls
         swept = self._engine.compactions - before
         self.stats.compactions += swept
-        if swept and self._warm_ops:
-            self.warmup(self._warm_ops)
+        if swept:
+            self._rewarm()
         self.stats.lanes += n
         for r in reqs:
             op_stats = self.stats.op(r.op)
@@ -516,19 +573,23 @@ class BSTServer:
             return self._empty_columns(op)
         pad = (-B) % self.chunk_size
         if pad:
-            a = np.pad(a, (0, pad))
-            if b is not None:
-                b = np.pad(b, (0, pad))
+            with self._span("pack"):
+                a = np.pad(a, (0, pad))
+                if b is not None:
+                    b = np.pad(b, (0, pad))
         if self._squery is not None:
             return self._serve_stream_sharded(op, a, b, B)
         columns = None
         for lo in range(0, a.size, self.chunk_size):
             sl = slice(lo, lo + self.chunk_size)
-            t0 = time.perf_counter()
-            res = self._query_chunk(op, a[sl], None if b is None else b[sl])
-            jax.block_until_ready(res)
-            dt = time.perf_counter() - t0
             real = min(self.chunk_size, B - lo)  # non-padded lanes this chunk
+            chunk = self.stats.chunks
+            t0 = time.perf_counter()
+            with self._span("dispatch", chunk=chunk, lanes=real):
+                res = self._query_chunk(op, a[sl], None if b is None else b[sl])
+            with self._span("sync", chunk=chunk):
+                jax.block_until_ready(res)
+            dt = time.perf_counter() - t0
             # range requests occupy TWO engine lanes each: the lo||hi
             # concatenated descent (DESIGN.md §6)
             lanes = real * (2 if op in RANGE_OPS else 1)
@@ -539,7 +600,8 @@ class BSTServer:
             ops.busy_s += dt
             ops.chunks += 1
             ops.lanes += lanes
-            columns = self._fill_columns(columns, a.size, sl, res)
+            with self._span("fetch", chunk=chunk):
+                columns = self._fill_columns(columns, a.size, sl, res)
             if op == "lookup":
                 # hits accumulated per chunk from the host columns the
                 # retire already paid for -- no extra device sync
@@ -579,13 +641,16 @@ class BSTServer:
         """
         columns = None
         found = 0
-        inflight: List[Tuple[slice, int, tuple]] = []
+        inflight: List[Tuple[slice, int, tuple, int]] = []
         n_chunks = 0
+        first = self.stats.chunks
 
-        def retire(r_sl: slice, r_lo: int, r_res: tuple):
+        def retire(r_sl: slice, r_lo: int, r_res: tuple, chunk: int):
             nonlocal columns, found
-            jax.block_until_ready(r_res)
-            columns = self._fill_columns(columns, a.size, r_sl, r_res)
+            with self._span("sync", chunk=chunk):
+                jax.block_until_ready(r_res)
+            with self._span("fetch", chunk=chunk):
+                columns = self._fill_columns(columns, a.size, r_sl, r_res)
             if op == "lookup":
                 real = min(self.chunk_size, B - r_lo)
                 found += int(columns[1][r_lo : r_lo + real].sum())
@@ -593,8 +658,10 @@ class BSTServer:
         t0 = time.perf_counter()
         for lo in range(0, a.size, self.chunk_size):
             sl = slice(lo, lo + self.chunk_size)
-            res = self._query_chunk(op, a[sl], None if b is None else b[sl])
-            inflight.append((sl, lo, res))
+            chunk = first + n_chunks
+            with self._span("dispatch", chunk=chunk, lanes=min(self.chunk_size, B - lo)):
+                res = self._query_chunk(op, a[sl], None if b is None else b[sl])
+            inflight.append((sl, lo, res, chunk))
             n_chunks += 1
             if len(inflight) > 1:  # depth-2 pipeline: retire the older chunk
                 retire(*inflight.pop(0))

@@ -282,3 +282,15 @@ def test_cli_exit_codes(tmp_path):
         == 0
     )
     assert out.exists()
+
+
+def test_gc_watch_counts_a_collection_and_unhooks():
+    import gc
+
+    hooks = list(gc.callbacks)
+    with runtime.gc_watch() as watch:
+        assert len(gc.callbacks) == len(hooks) + 1
+        gc.collect()
+    assert watch.collections[2] >= 1 and watch.seconds[2] > 0
+    assert watch.total_s >= watch.seconds[2]
+    assert gc.callbacks == hooks

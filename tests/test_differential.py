@@ -18,8 +18,8 @@ writes before it).  Coverage axes:
     server and must match BIT-FOR-BIT, for hrz / dup / hyb x kernel /
     reference descent x pre-/post-compaction, live writes included; plus
     a ≥ 500-op mixed read/write soak per mix ratio that cross-checks the
-    per-op ``OpStats`` lane accounting and the ``keys_per_sec`` /
-    ``lanes_per_sec`` invariants against the submitted op counts.
+    per-op ``OpStats`` lane accounting against the submitted op counts
+    and the phase counters against the drains.
 
 Runs under real hypothesis or the deterministic ``_hypothesis_fallback``
 shim alike (the strategies stick to the shim's subset).  Reads are flushed
@@ -482,11 +482,11 @@ def test_sharded_server_soak_mixed_accounting(multi_device_host):
     """≥ 500-op mixed read/write soak through the sharded server, per mix.
 
     Beyond correctness (lookups cross-checked against a dict oracle), the
-    per-op ``OpStats`` lane accounting and throughput figures must tie out
-    EXACTLY against the submitted op counts: one lane per point/write/
-    delete key, two per range request, busy seconds partitioning into the
-    per-op attributions, and keys/lanes-per-sec being served/lanes over
-    busy time."""
+    per-op ``OpStats`` lane accounting must tie out EXACTLY against the
+    submitted op counts: one lane per point/write/delete key, two per
+    range request, busy seconds partitioning into the per-op
+    attributions; and the phase counters must count every drain and hold
+    the sharded scheduler's spans inside the read busy time."""
     multi_device_host("""
         from repro.core import distributed as D
         from repro.core.engine import EngineConfig
@@ -549,16 +549,12 @@ def test_sharded_server_soak_mixed_accounting(multi_device_host):
                 lanes = 2 * n if op.startswith("range") else n
                 assert st.lanes == lanes, (mix, op, st.lanes, lanes)
                 assert st.chunks > 0 and st.busy_s > 0, (mix, op)
-                # the throughput figures ARE served/lanes over busy time
-                assert abs(st.keys_per_sec * st.busy_s - st.served) < 1e-6
-                assert abs(st.lanes_per_sec * st.busy_s - st.lanes) < 1e-6
             assert s.lanes == sum(
                 (2 * n if op.startswith("range") else n)
                 for op, n in counts.items()
             )
             assert sum(st.lanes for st in s.per_op.values()) == s.lanes
-            assert abs(s.keys_per_sec * s.busy_s - s.served) < 1e-6
-            assert abs(s.lanes_per_sec * s.busy_s - s.lanes) < 1e-6
+            assert s.drains == -(-n_ops // 80)  # one per flush of the loop
             # read busy attributions partition the read-span walls; write
             # spans attribute their whole wall across their requests
             read_busy = sum(
@@ -570,6 +566,11 @@ def test_sharded_server_soak_mixed_accounting(multi_device_host):
                 if op in ("write", "delete")
             )
             assert abs(read_busy + write_busy - s.busy_s) < 1e-6, mix
+            # the read spans lie inside the pipelines' busy walls, the
+            # ingest span is the write spans' busy time less compaction
+            p = s.phase_s
+            assert p["dispatch"] + p["sync"] + p["fetch"] <= read_busy, mix
+            assert p["ingest"] + p["compact"] <= write_busy, mix
             assert s.updates == counts["write"] + counts["delete"]
             assert s.compactions > 0, mix  # the soak crosses the high-water
             print("ok", mix, "ops", n_ops, "compactions", s.compactions)

@@ -1,6 +1,8 @@
 """BSTServer: chunk accumulation, accounting, snapshot-swap serving."""
 
 import dataclasses
+import time
+import types
 
 import numpy as np
 import jax.numpy as jnp
@@ -112,9 +114,11 @@ def test_per_op_busy_attribution_by_lanes():
     assert s.per_op["range_count"].lanes == 120  # lo||hi: 2 lanes per range
     assert s.lanes == 220
     assert sum(o.busy_s for o in s.per_op.values()) == pytest.approx(s.busy_s)
-    assert s.per_op["range_count"].lanes_per_sec == pytest.approx(
-        120 / s.per_op["range_count"].busy_s
-    )
+    # one drain of one read span: the engine calls' dispatch and sync
+    # spans are the busy time, and every span lies inside the drain
+    assert s.drains == 1
+    assert set(s.phase_s) == {"drain", "pack", "dispatch", "sync", "fetch", "unpack"}
+    assert s.phase_s["dispatch"] + s.phase_s["sync"] <= s.busy_s
 
     srv.reset_stats()
     # a mixed write+delete span rides shared engine calls: time splits by
@@ -140,3 +144,118 @@ def test_swap_applies_to_pending_requests():
     srv.apply_updates(insert_keys=absent, insert_values=np.array([42], np.int32))
     v, f = srv.drain()[t]
     assert bool(f[0]) and int(v[0]) == 42
+
+
+READ_SPANS = {"drain", "pack", "dispatch", "sync", "fetch", "unpack"}
+
+
+def test_drain_records_phase_spans(monkeypatch):
+    """A read drain is split into its phase spans: together they are at
+    most the drain's wall time, and the engine calls' dispatch and sync
+    spans are its busy time.  The server's clock is a fake one that moves
+    1 µs per reading and 1 ms in each engine call and each wait, so the
+    comparison does not hang on how busy the test machine is."""
+    import jax
+
+    from repro.serving import bst_server
+
+    keys, values = make_tree_data(4095, seed=3)
+    srv = BSTServer(keys, values, EngineConfig(strategy="hrz"), chunk_size=2048)
+    srv.warmup()
+    now = [0.0]
+
+    def clock():
+        now[0] += 1e-6
+        return now[0]
+
+    def slow(fn):
+        def call(*args):
+            now[0] += 1e-3
+            return fn(*args)
+        return call
+
+    monkeypatch.setattr(bst_server, "time", types.SimpleNamespace(perf_counter=clock))
+    monkeypatch.setattr(bst_server, "jax", types.SimpleNamespace(
+        profiler=jax.profiler, block_until_ready=slow(jax.block_until_ready)))
+    monkeypatch.setattr(srv, "_query_chunk", slow(srv._query_chunk))
+    rng = np.random.default_rng(4)
+    for n in (1, 2047, 3000):  # two full chunks and a padded one
+        srv.submit(rng.choice(keys, n).astype(np.int32))
+    t0 = clock()
+    srv.drain()
+    wall = clock() - t0
+    s = srv.stats
+    assert set(s.phase_s) == READ_SPANS
+    assert sum(s.phase_s.values()) <= wall
+    engine = s.phase_s["dispatch"] + s.phase_s["sync"]
+    assert s.chunks == 3 and s.busy_s >= 6e-3
+    assert 0.9 * s.busy_s <= engine <= s.busy_s, (engine, s.busy_s)
+
+
+def test_queue_wait_and_drains_count_once_per_drain(monkeypatch):
+    """Each drain adds one to ``drains`` and the wait of its oldest request
+    to ``queue_wait_s``; submit reads the clock once per drain, not per
+    request, and an empty drain counts nothing."""
+    from repro.serving import bst_server
+
+    keys, values = make_tree_data(255, seed=5)
+    srv = BSTServer(keys, values, chunk_size=64)
+    srv.warmup()
+    reads = []
+
+    def counted():
+        reads.append(1)
+        return time.perf_counter()
+
+    for _ in range(3):
+        with monkeypatch.context() as m:
+            m.setattr(bst_server, "time", types.SimpleNamespace(perf_counter=counted))
+            for k in keys[:20]:
+                srv.submit(k)
+        time.sleep(0.01)
+        srv.drain()
+    assert len(reads) == 3  # one per drain, at its first request
+    assert srv.drain() == {}
+    assert srv.stats.drains == 3
+    assert 0.03 <= srv.stats.queue_wait_s < 3.0
+
+
+def test_write_span_through_a_compaction_records_ingest_compact_rewarm():
+    """Writes that cross the high-water mark run the ingest, compaction and
+    re-warm spans; the compaction's time counts for ``compact`` alone, and
+    ingest and compaction together are the write span's busy time."""
+    keys, values = make_tree_data(500, seed=6)
+    cfg = EngineConfig(strategy="hrz", delta_capacity=64, delta_high_water=48)
+    srv = BSTServer(keys, values, cfg, chunk_size=128)
+    srv.warmup()
+    srv.submit_write(np.arange(1, 101, 2, dtype=np.int32), np.ones(50, np.int32))
+    srv.drain()
+    s = srv.stats
+    assert s.compactions == 1
+    assert {"drain", "ingest", "compact", "rewarm"} == set(s.phase_s)
+    assert s.phase_s["ingest"] + s.phase_s["compact"] <= s.busy_s
+
+
+def test_sharded_server_records_the_same_spans(multi_device_host):
+    """The double-buffered scheduler over four forced host devices records
+    the single-chip loop's span names, write path included."""
+    out = multi_device_host("""
+        from repro.core import distributed as D
+        from repro.core.engine import EngineConfig
+        from repro.data.keysets import make_tree_data
+        from repro.serving import BSTServer
+
+        keys, values = make_tree_data(500, seed=6)
+        cfg = EngineConfig(strategy="hrz", delta_capacity=64, delta_high_water=48)
+        srv = BSTServer(keys, values, cfg, chunk_size=64, mesh=D.make_serving_mesh("hrz"))
+        srv.warmup()
+        srv.submit(keys[:100])
+        srv.submit_write(np.arange(1, 101, 2, dtype=np.int32), np.ones(50, np.int32))
+        srv.submit(keys[100:150])
+        srv.drain()
+        s = srv.stats
+        assert s.compactions == 1 and s.drains == 1
+        print(sorted(s.phase_s))
+    """, devices=4, timeout=900)
+    names = out.strip().splitlines()[-1]
+    assert names == str(sorted(READ_SPANS | {"ingest", "compact", "rewarm"}))
