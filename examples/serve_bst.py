@@ -97,8 +97,9 @@ def main():
     print(f"{'op':12s} {'served':>10s} {'chunks':>7s} {'busy ms':>10s}")
     for op, st in srv.stats.per_op.items():
         print(f"{op:12s} {st.served:10d} {st.chunks:7d} {st.busy_s * 1e3:10.1f}")
-    phases = srv.stats.phase_s.items()
-    print("host ms by phase: " + ", ".join(f"{k} {v * 1e3:.1f}" for k, v in phases))
+    s = srv.stats
+    print("host ms by phase: " + ", ".join(f"{k} {v * 1e3:.1f}" for k, v in s.phase_s.items())
+          + f"; row answers {s.row_answers} of {s.requests} requests")
 
     # ---- live write path: delta-buffered updates, compaction, no rebuilds
     cfg = dataclasses.replace(PAPER_CONFIGS["Hyb8q"], delta_capacity=4096)
@@ -123,8 +124,8 @@ def main():
         f"{s.served / dt:.0f} keys/s end-to-end, {s.updates} updates absorbed "
         f"on device, {s.compactions} compaction(s), 0 rebuilds"
     )
-    phases = s.phase_s.items()
-    print("  host ms by phase: " + ", ".join(f"{k} {v * 1e3:.1f}" for k, v in phases))
+    print("  host ms by phase: " + ", ".join(f"{k} {v * 1e3:.1f}" for k, v in s.phase_s.items())
+          + f"; row answers {s.row_answers} of {s.requests} requests")
     v, f = srv.lookup(wk[half + 1 : half + 9])
     print(f"  post-write lookups: found {int(np.asarray(f).sum())}/8 fresh keys")
 

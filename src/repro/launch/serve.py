@@ -62,7 +62,8 @@ def bst_main(args) -> None:
         f"{args.requests} lookups in {dt:.2f}s "
         f"({s.served / dt:.0f} ops/s, {s.found} found, {s.chunks} chunks)"
     )
-    print("host ms by phase: " + ", ".join(f"{k} {v * 1e3:.1f}" for k, v in s.phase_s.items()))
+    print("host ms by phase: " + ", ".join(f"{k} {v * 1e3:.1f}" for k, v in s.phase_s.items())
+          + f"; row answers {s.row_answers} of {s.requests} requests")
 
     # a mixed tail: writes ride the replicated delta buffer on-device
     wk = rng.integers(1, 2**20, args.chunk).astype(np.int32)
@@ -75,8 +76,9 @@ def bst_main(args) -> None:
         f"{int(np.asarray(f).sum())}/16 fresh keys found, "
         f"{srv.stats.compactions} compaction(s)"
     )
-    phases = srv.stats.phase_s.items()
-    print("host ms by phase, all drains: " + ", ".join(f"{k} {v * 1e3:.1f}" for k, v in phases))
+    s = srv.stats
+    print("host ms by phase, all drains: " + ", ".join(f"{k} {v * 1e3:.1f}" for k, v in s.phase_s.items())
+          + f"; row answers {s.row_answers} of {s.requests} requests")
 
 
 def main(argv=None):

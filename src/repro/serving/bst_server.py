@@ -14,6 +14,16 @@ atomically between chunks.  This module is that loop, TPU-native:
     into fixed ``chunk_size`` engine calls (the jit shape), padding only the
     final partial chunk per op; per-request results are sliced back out, so
     padded lanes never leak into answers or accounting;
+  * **columnar queue** -- the pending queue is three parallel lists (op,
+    keys or range lows, range highs or write values), not one object per
+    request, and tickets are implicit: request ``i`` of the queue holds
+    ticket ``first + i``.  ``submit`` queues an int32 vector as it is (no
+    copy, no conversion call).  The drain tests the op column in C: a queue
+    of one op kind is one read span and one group, whose keys are one
+    ``np.concatenate``.  Where every request of a group holds one key (or
+    one range), its answers are the result columns' rows, built in C as
+    shape-``(1, ...)`` views (``ServerStats.row_answers`` counts them);
+    any other group is sliced request by request;
   * **pluggable engine config** -- any ``EngineConfig`` (strategy, mapping,
     kernel/reference path) serves the same request API;
   * **live write path** (DESIGN.md §7) -- with
@@ -86,6 +96,8 @@ RANGE_OPS = plans_lib.RANGE_OPS
 POINT_OPS = tuple(op for op in plans_lib.QUERY_OPS if op not in RANGE_OPS)
 # Mutating request kinds (DESIGN.md §7); these are drain-order barriers.
 WRITE_OPS = ("write", "delete")
+_WRITE_SET = frozenset(WRITE_OPS)
+_INT32 = np.dtype(np.int32)
 
 
 @dataclasses.dataclass
@@ -103,7 +115,7 @@ class OpStats:
     lanes: int = 0
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(slots=True)  # slots: submit() bumps two counters per request
 class ServerStats:
     """Cumulative serving counters (reset with ``BSTServer.reset_stats``)."""
 
@@ -126,17 +138,12 @@ class ServerStats:
     # Summed over drains: the drain's start minus the enqueue time of its
     # oldest request.
     queue_wait_s: float = 0.0
+    # Requests answered by row views: every request of their op group held
+    # one key or one range (see ``BSTServer._unpack``).
+    row_answers: int = 0
 
     def op(self, name: str) -> OpStats:
         return self.per_op.setdefault(name, OpStats())
-
-
-@dataclasses.dataclass
-class _Request:
-    ticket: int
-    op: str
-    a: np.ndarray  # keys (point / write / delete ops) / range lows
-    b: Optional[np.ndarray]  # range highs (range ops) / write values
 
 
 class _Span:
@@ -170,6 +177,19 @@ class _Span:
         phase_s = self._server.stats.phase_s
         phase_s[self._name] = phase_s.get(self._name, 0.0) + dt - self._inner
         self._annotation.__exit__(*exc)
+
+
+def _order_spans(ops: List[str]) -> List[Tuple[int, int]]:
+    """``[lo, hi)`` bounds of the maximal runs of reads and of writes in
+    ``ops``, in order.  A queue of one op kind, or of reads alone, is one
+    run, found by C-level scans; any other is walked request by request."""
+    n = len(ops)
+    if ops.count(ops[0]) == n or _WRITE_SET.isdisjoint(ops):
+        return [(0, n)]
+    bounds = [
+        i for i in range(1, n) if (ops[i] in WRITE_OPS) != (ops[i - 1] in WRITE_OPS)
+    ]
+    return list(zip([0] + bounds, bounds + [n]))
 
 
 class BSTServer:
@@ -213,10 +233,15 @@ class BSTServer:
             invariants.check_chunk_divides(chunk_size, mesh.shape[axis], axis)
         self.stats = ServerStats()
         self._open_spans: List[_Span] = []
-        self._pending: List[_Request] = []
-        self._pending_keys = 0
+        # The pending queue, one list per field of a request: its op, its
+        # keys (point / write / delete ops) or range lows, and its range
+        # highs or write values (None otherwise).  Request i holds ticket
+        # ``_first_ticket + i``.
+        self._ops: List[str] = []
+        self._a: List[np.ndarray] = []
+        self._b: List[Optional[np.ndarray]] = []
+        self._first_ticket = 0
         self._oldest_t = 0.0  # enqueue time of the oldest pending request
-        self._next_ticket = 0
         self._warm_ops: Tuple[str, ...] = ()
         # Fixed jit shape for delta-buffer write chunks (DESIGN.md §7): one
         # compiled ingest program regardless of request sizes.
@@ -348,10 +373,13 @@ class BSTServer:
         """
         if op not in POINT_OPS:
             raise ValueError(f"submit() op must be one of {POINT_OPS}, got {op!r}")
-        req = np.atleast_1d(np.asarray(request_keys, np.int32))
-        if req.ndim != 1:
-            raise ValueError("request_keys must be scalar or 1-D")
-        return self._enqueue(_Request(0, op, req, None), req.size)
+        req = request_keys
+        # An int32 vector is what the conversion would return as it is.
+        if not (type(req) is np.ndarray and req.dtype is _INT32 and req.ndim == 1):
+            req = np.atleast_1d(np.asarray(req, np.int32))
+            if req.ndim != 1:
+                raise ValueError("request_keys must be scalar or 1-D")
+        return self._enqueue(op, req, None)
 
     def submit_range(self, lo, hi, op: str = "range_count") -> int:
         """Queue a range request over [lo, hi] (inclusive); returns a ticket.
@@ -365,7 +393,7 @@ class BSTServer:
         hi = np.atleast_1d(np.asarray(hi, np.int32))
         if lo.shape != hi.shape or lo.ndim != 1:
             raise ValueError("lo/hi must be equal-length scalars or 1-D arrays")
-        return self._enqueue(_Request(0, op, lo, hi), lo.size)
+        return self._enqueue(op, lo, hi)
 
     def submit_write(self, request_keys, request_values) -> int:
         """Queue an upsert request (DESIGN.md §7); returns a ticket.
@@ -380,7 +408,7 @@ class BSTServer:
         v = np.atleast_1d(np.asarray(request_values, np.int32))
         if k.shape != v.shape or k.ndim != 1:
             raise ValueError("keys/values must be equal-length scalars or 1-D")
-        return self._enqueue(_Request(0, "write", k, v), k.size)
+        return self._enqueue("write", k, v)
 
     def submit_delete(self, request_keys) -> int:
         """Queue a delete (tombstone) request; returns a ticket.
@@ -392,7 +420,7 @@ class BSTServer:
         k = np.atleast_1d(np.asarray(request_keys, np.int32))
         if k.ndim != 1:
             raise ValueError("request_keys must be scalar or 1-D")
-        return self._enqueue(_Request(0, "delete", k, None), k.size)
+        return self._enqueue("delete", k, None)
 
     def _require_write_path(self) -> None:
         if self._engine.delta is None:
@@ -401,20 +429,21 @@ class BSTServer:
                 " > 0); use apply_updates() for bulk snapshot swaps"
             )
 
-    def _enqueue(self, req: _Request, size: int) -> int:
-        if not self._pending:
+    def _enqueue(self, op: str, a: np.ndarray, b: Optional[np.ndarray]) -> int:
+        ops = self._ops
+        if not ops:
             self._oldest_t = time.perf_counter()  # once per drain, not per request
-        req.ticket = self._next_ticket
-        self._next_ticket += 1
-        self._pending.append(req)
-        self._pending_keys += size
-        self.stats.requests += 1
-        self.stats.submitted += size
-        return req.ticket
+        ops.append(op)
+        self._a.append(a)
+        self._b.append(b)
+        stats = self.stats
+        stats.requests += 1
+        stats.submitted += a.size
+        return self._first_ticket + len(ops) - 1
 
     def pending(self) -> int:
         """Keys/ranges queued but not yet served."""
-        return self._pending_keys
+        return sum(map(len, self._a))
 
     # ------------------------------------------------------------------ drain
     def drain(self) -> Dict[int, tuple]:
@@ -435,60 +464,76 @@ class BSTServer:
         final partial chunks are padded, and padded lanes never reach
         results or accounting.
         """
-        if not self._pending:
+        ops = self._ops
+        if not ops:
             return {}
-        batch = self._pending
-        keys = self._pending_keys
-        self._pending = []
-        self._pending_keys = 0
+        a, b = self._a, self._b
+        first, n, keys = self._first_ticket, len(ops), sum(map(len, a))
+        self._ops, self._a, self._b = [], [], []
+        self._first_ticket = first + n
         self.stats.drains += 1
         self.stats.queue_wait_s += time.perf_counter() - self._oldest_t
 
         out: Dict[int, tuple] = {}
-        span: List[_Request] = []
         # The ids link every request, by ticket, to the drain that served it.
-        with self._span("drain", first_ticket=batch[0].ticket, requests=len(batch), keys=keys):
-            for req in batch:
-                if req.op in WRITE_OPS:
-                    if span and span[-1].op not in WRITE_OPS:
-                        self._serve_read_span(span, out)
-                        span = []
-                elif span and span[-1].op in WRITE_OPS:
-                    self._serve_write_span(span, out)
-                    span = []
-                span.append(req)
-            if span:
-                if span[-1].op in WRITE_OPS:
-                    self._serve_write_span(span, out)
-                else:
-                    self._serve_read_span(span, out)
+        with self._span("drain", first_ticket=first, requests=n, keys=keys):
+            for lo, hi in _order_spans(ops):
+                serve = self._serve_write_span if ops[lo] in WRITE_OPS else self._serve_read_span
+                serve(first + lo, ops[lo:hi], a[lo:hi], b[lo:hi], out)
             # Freeing the served requests costs about as much as packing
             # them: free them inside the span, which then holds all of it.
-            del batch, span
+            del ops, a, b
         return out
 
-    def _serve_read_span(self, reqs: List[_Request], out: Dict[int, tuple]):
-        """One writeless span: requests commute, so pack per op kind."""
+    def _serve_read_span(self, first: int, ops: List[str], a: list, b: list,
+                         out: Dict[int, tuple]):
+        """One writeless span (request ``i`` holds ticket ``first + i``):
+        requests commute, so pack per op kind."""
         with self._span("pack"):
-            by_op: Dict[str, List[_Request]] = {}
-            for req in reqs:
-                by_op.setdefault(req.op, []).append(req)
+            if ops.count(ops[0]) == len(ops):  # one op kind: one group
+                groups = [(ops[0], range(first, first + len(ops)), a, b)]
+            else:
+                by_op: Dict[str, List[int]] = {}
+                for i, op in enumerate(ops):
+                    by_op.setdefault(op, []).append(i)
+                groups = [
+                    (op, [first + i for i in idx], [a[i] for i in idx], [b[i] for i in idx])
+                    for op, idx in by_op.items()
+                ]
             streams = [
-                (op, group, np.concatenate([r.a for r in group]),
-                 np.concatenate([r.b for r in group]) if op in RANGE_OPS else None)
-                for op, group in by_op.items()
+                (op, tickets, lows, np.concatenate(lows),
+                 np.concatenate(highs) if op in RANGE_OPS else None)
+                for op, tickets, lows, highs in groups
             ]
-        for op, group, a, b in streams:
-            columns = self._serve_stream(op, a, b)
+        for op, tickets, lows, a_all, b_all in streams:
+            columns = self._serve_stream(op, a_all, b_all)
             with self._span("unpack"):
-                lo = 0
-                for r in group:
-                    hi = lo + r.a.size
-                    out[r.ticket] = tuple(col[lo:hi] for col in columns)
-                    lo = hi
+                self._unpack(tickets, lows, columns, out)
 
-    def _serve_write_span(self, reqs: List[_Request], out: Dict[int, tuple]):
-        """One run of consecutive write/delete requests -> delta ingest.
+    def _unpack(self, tickets, lows: list, columns, out: Dict[int, tuple]):
+        """Hand each request of one op group its slice of the result columns.
+
+        Where every request holds one key (or one range), each answer is a
+        row of the columns: a tuple of shape-``(1, ...)`` views, the shape,
+        dtype and values that slicing gives, built in C.  A group with a
+        request of any other size is sliced request by request.
+        """
+        n = len(tickets)
+        if columns[0].shape[0] == n and min(map(len, lows)) == 1:
+            rows = (col.reshape((n, 1) + col.shape[1:]) for col in columns)
+            out.update(zip(tickets, zip(*rows)))
+            self.stats.row_answers += n
+            return
+        lo = 0
+        for ticket, x in zip(tickets, lows):
+            hi = lo + x.size
+            out[ticket] = tuple(col[lo:hi] for col in columns)
+            lo = hi
+
+    def _serve_write_span(self, first: int, ops: List[str], a: list, b: list,
+                          out: Dict[int, tuple]):
+        """One run of consecutive write/delete requests -> delta ingest
+        (request ``i`` holds ticket ``first + i``).
 
         Consecutive mutations merge into a single submission-ordered batch
         (the buffer's last-wins dedup preserves exactly that order), padded
@@ -496,13 +541,11 @@ class BSTServer:
         swap the snapshot between chunks; the server then re-warms the jit
         cache so later read chunks stay compile-free.
         """
-        keys = np.concatenate([r.a for r in reqs])
+        keys = np.concatenate(a)
         values = np.concatenate(
-            [r.b if r.op == "write" else np.zeros(r.a.size, np.int32) for r in reqs]
+            [v if op == "write" else np.zeros(k.size, np.int32) for op, k, v in zip(ops, a, b)]
         )
-        deletes = np.concatenate(
-            [np.full(r.a.size, r.op == "delete") for r in reqs]
-        )
+        deletes = np.concatenate([np.full(k.size, op == "delete") for op, k in zip(ops, a)])
         pad = (-keys.size) % self._write_chunk
         valid = np.ones(keys.size + pad, bool)
         if pad:
@@ -535,18 +578,18 @@ class BSTServer:
         if swept:
             self._rewarm()
         self.stats.lanes += n
-        for r in reqs:
-            op_stats = self.stats.op(r.op)
-            op_stats.served += r.a.size
+        for ticket, op, k in zip(range(first, first + len(ops)), ops, a):
+            op_stats = self.stats.op(op)
+            op_stats.served += k.size
             # Busy attribution is by the lanes the request actually
             # occupied in the span's engine calls (one per write/delete
             # key; ``n`` counts every occupied lane in the span, so shares
             # sum to exactly ``dt`` and padding cost is borne
             # proportionally -- a request's op kind never skews it).
-            op_stats.busy_s += dt * (r.a.size / max(n, 1))
-            op_stats.lanes += r.a.size
-            out[r.ticket] = (np.asarray(r.a.size, np.int32),)
-        for kind in {r.op for r in reqs}:
+            op_stats.busy_s += dt * (k.size / max(n, 1))
+            op_stats.lanes += k.size
+            out[ticket] = (np.asarray(k.size, np.int32),)
+        for kind in set(ops):
             # a mixed span's engine calls served both kinds; each kind
             # records every call it rode in (same rule as busy_s sharing)
             self.stats.op(kind).chunks += n_calls

@@ -1,6 +1,7 @@
 """BSTServer: chunk accumulation, accounting, snapshot-swap serving."""
 
 import dataclasses
+import os
 import time
 import types
 
@@ -259,3 +260,157 @@ def test_sharded_server_records_the_same_spans(multi_device_host):
     """, devices=4, timeout=900)
     names = out.strip().splitlines()[-1]
     assert names == str(sorted(READ_SPANS | {"ingest", "compact", "rewarm"}))
+
+
+READ_OPS = ("lookup", "predecessor", "successor", "range_count", "range_scan")
+
+
+def _drain_script(shape: str, keys, seed: int = 0):
+    """One drain's requests, in submission order, as ``(method, args)``
+    pairs of ``BSTServer``.  ``one-key``: lookups of one key each, as
+    arrays and as scalars, over several chunks; ``one-key-every-op``: one
+    key or one range each, every read op in one span; ``two-and-none``:
+    lookups of two keys and of none in turn, as many keys as requests;
+    ``mixed``: scalar, one-key, multi-key and empty requests of every read
+    op, with writes and deletes between them."""
+    rng = np.random.default_rng(seed)
+    pool = np.concatenate([keys, keys + 1])
+
+    def some(n):
+        return rng.choice(pool, n).astype(np.int32)
+
+    def read(op, form):
+        n = {"scalar": 1, "one": 1, "two": 2, "multi": 3, "empty": 0}[form]
+        lo = some(n)
+        if form == "scalar":
+            lo = int(lo[0]) if rng.random() < 0.5 else np.int32(lo[0])
+        if op in ("range_count", "range_scan"):
+            return ("submit_range", (lo, lo + 9, op))
+        return ("submit", (lo, op))
+
+    if shape == "one-key":
+        return [read("lookup", "scalar" if i % 5 == 0 else "one") for i in range(150)]
+    if shape == "one-key-every-op":
+        return [read(READ_OPS[i % 5], "scalar" if i % 7 == 0 else "one") for i in range(60)]
+    if shape == "two-and-none":
+        return [read("lookup", ("two", "empty")[i % 2]) for i in range(40)]
+    script = []
+    for i in range(80):
+        if i % 9 == 4:
+            k = some(1 + i % 3)
+            script.append(("submit_write", (k, k * 3)))
+        elif i % 13 == 7:
+            script.append(("submit_delete", (some(2),)))
+        else:
+            script.append(read(READ_OPS[i % 5], ("scalar", "one", "multi", "empty")[i % 4]))
+    return script
+
+
+def _row_answers(script) -> int:
+    """Requests of the op groups, per read span, whose every request holds
+    one key or one range: those the drain answers by row views."""
+    count, groups = 0, {}
+    for method, args in script + [("submit_write", None)]:
+        if method in ("submit_write", "submit_delete"):
+            count += sum(len(g) for g in groups.values() if all(n == 1 for n in g))
+            groups = {}
+        else:
+            groups.setdefault(args[-1], []).append(np.size(args[0]))
+    return count
+
+
+def check_columnar_drain(make_server, script, rounds: int = 2) -> None:
+    """Serve ``script`` as one drain per round and each request alone on a
+    second server: every answer agrees in shape, dtype and values, the
+    tickets run on from round to round, and ``row_answers`` counts the
+    requests of the all-single-key groups.  Alone, a read is drained beside
+    an empty request of its kind, so its answer is sliced, never a row."""
+    srv, alone = make_server(), make_server()
+    empty = np.zeros(0, np.int32)
+    n = len(script)
+    for r in range(rounds):
+        tickets = [getattr(srv, m)(*args) for m, args in script]
+        assert tickets == list(range(r * n, (r + 1) * n))
+        out = srv.drain()
+        assert sorted(out) == tickets
+        for t, (m, args) in zip(tickets, script):
+            solo = getattr(alone, m)(*args)
+            if m == "submit":
+                alone.submit(empty, args[-1])
+            elif m == "submit_range":
+                alone.submit_range(empty, empty, args[-1])
+            want, got = alone.drain()[solo], out[t]
+            assert len(got) == len(want), (m, args)
+            for g, w in zip(got, want):
+                assert (g.shape, g.dtype) == (w.shape, w.dtype), (m, args)
+                np.testing.assert_array_equal(g, w)
+        assert srv.stats.row_answers == (r + 1) * _row_answers(script)
+    assert alone.stats.row_answers == 0
+    srv.reset_stats()
+    assert srv.stats.row_answers == 0
+
+
+@pytest.mark.parametrize("shape", ["one-key", "one-key-every-op", "two-and-none", "mixed"])
+@pytest.mark.parametrize("strategy", ["hrz", "hyb"])
+def test_columnar_drain_answers_as_each_request_alone(shape, strategy):
+    keys, values = make_tree_data(500, seed=8)
+    cfg = dataclasses.replace(
+        PAPER_CONFIGS["Hrz" if strategy == "hrz" else "Hyb8q"],
+        delta_capacity=64, delta_high_water=48,
+    )
+
+    def make():
+        return BSTServer(keys, values, cfg, chunk_size=64)
+
+    script = _drain_script(shape, keys)
+    if shape == "one-key":
+        assert _row_answers(script) == len(script)
+    check_columnar_drain(make, script)
+
+
+def test_sharded_columnar_drain_answers_as_each_request_alone(multi_device_host):
+    """The same check through the double-buffered sharded scheduler."""
+    out = multi_device_host(f"""
+        sys.path.insert(0, {os.path.dirname(os.path.abspath(__file__))!r})
+        from repro.core import distributed as D
+        from repro.core.engine import EngineConfig
+        from repro.data.keysets import make_tree_data
+        from repro.serving import BSTServer
+        from test_serving_bst import _drain_script, check_columnar_drain
+
+        keys, values = make_tree_data(500, seed=8)
+        cfg = EngineConfig(strategy="hrz", delta_capacity=64, delta_high_water=48)
+        mesh = D.make_serving_mesh("hrz")
+        check_columnar_drain(
+            lambda: BSTServer(keys, values, cfg, chunk_size=64, mesh=mesh),
+            _drain_script("mixed", keys), rounds=1)
+        print("columnar ok")
+    """, devices=4, timeout=900)
+    assert out.strip().splitlines()[-1] == "columnar ok"
+
+
+_K = np.arange(4, dtype=np.int32)
+_BAD_REQUESTS = {
+    "point-op-range": ("submit", (_K, "range_count")),
+    "point-op-write": ("submit", (_K, "write")),
+    "range-op-point": ("submit_range", (_K, _K, "lookup")),
+    "2d-int32-keys": ("submit", (_K.reshape(2, 2),)),
+    "2d-int64-keys": ("submit", (_K.reshape(2, 2).astype(np.int64),)),
+    "2d-range": ("submit_range", (_K.reshape(2, 2), _K.reshape(2, 2))),
+    "lo-hi-lengths": ("submit_range", (_K, _K[:3])),
+    "keys-values-lengths": ("submit_write", (_K, _K[:3])),
+    "2d-write": ("submit_write", (_K.reshape(2, 2), _K.reshape(2, 2))),
+    "2d-delete": ("submit_delete", (_K.reshape(2, 2),)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_BAD_REQUESTS))
+def test_bad_requests_raise_and_queue_nothing(name):
+    keys, values = make_tree_data(100, seed=2)
+    srv = BSTServer(keys, values, EngineConfig(delta_capacity=64), chunk_size=64)
+    method, args = _BAD_REQUESTS[name]
+    with pytest.raises(ValueError):
+        getattr(srv, method)(*args)
+    assert srv.pending() == 0 and srv.stats.requests == 0
+    assert srv.drain() == {}
+    assert srv.submit(keys[:2]) == 0  # the ticket count did not move
