@@ -196,7 +196,9 @@ def main():
         srv.drain()
         dt = time.perf_counter() - t0
         s = srv.stats
-        print(f"{strategy:10s} {s.served / dt:12.0f} {s.chunks:7d} {s.found:10d}")
+        print(f"{strategy:10s} {s.served / dt:12.0f} {s.chunks:7d} {s.found:10d}"
+              f"  (router: {s.routed_slots} slots for {s.lanes} lanes, "
+              f"{s.overlapped_chunks} chunks overlapped)")
 
     # live writes through the sharded hybrid server: the delta buffer rides
     # every sharded read as replicated operands, folded on-device

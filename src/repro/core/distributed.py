@@ -59,6 +59,7 @@ contract, so ``BSTServer`` shards by flipping a constructor argument.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -69,6 +70,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.sharding.compat import shard_map
 
 from repro.analysis import invariants
+from repro.analysis import runtime as analysis_runtime
 from repro.core import delta as delta_lib
 from repro.core import plans as plans_lib
 from repro.core import tree as tree_lib
@@ -102,22 +104,32 @@ def stored_nodes_per_device(*arrays) -> int:
 def shard_subtrees(
     tree: TreeData, mesh: Mesh, axis: str
 ) -> Tuple[jax.Array, jax.Array, int, int]:
-    """Vertical-partition the tree across ``axis``: (M, sub_n) arrays."""
+    """Vertical-partition the tree across ``axis``: (M, sub_n) arrays.
+
+    The subtrees are gathered on the host and each device receives only
+    its own row: no device ever holds the whole tree (DESIGN.md §9)."""
     M = mesh.shape[axis]
     split_level = invariants.check_power_of_two(M, f"mesh axis {axis} size")
     if split_level > tree.height:
         raise ValueError("tree shallower than the mesh axis")
     idx = tree_lib.all_subtree_gather_indices(tree.height, split_level)
-    sub_keys = jnp.asarray(np.asarray(tree.keys)[idx])
-    sub_vals = jnp.asarray(np.asarray(tree.values)[idx])
     sharding = NamedSharding(mesh, P(axis, None))
-    sub_keys = jax.device_put(sub_keys, sharding)
-    sub_vals = jax.device_put(sub_vals, sharding)
+    sub_keys = jax.device_put(np.asarray(tree.keys)[idx], sharding)
+    sub_vals = jax.device_put(np.asarray(tree.values)[idx], sharding)
     return sub_keys, sub_vals, split_level, tree.height - split_level
 
 
+def on_host(tree: TreeData) -> TreeData:
+    """``tree`` with its arrays in host memory, where a sharded server keeps
+    its snapshot (a host tree is returned as it is)."""
+    if isinstance(tree.keys, np.ndarray):
+        return tree
+    keys, values = analysis_runtime.device_fetch((tree.keys, tree.values))
+    return TreeData(keys, values, tree.height, tree.n_real)
+
+
 def _make_query_runner(
-    descend, tree: TreeData, rank_to_bfs: jax.Array, lookup=None
+    descend, tree: TreeData, mesh: Mesh, axis: str, lookup=None
 ):
     """Wrap a sharded ordered-descent into the ``run(op, ...)`` contract.
 
@@ -134,29 +146,39 @@ def _make_query_runner(
     ``(queries, d_ops) -> (values, found)`` sharded program (the engine's
     own §6 rule -- the hot lookup path pays nothing for the ordered
     datapath).  Without it lookups ride the ordered descent and take its
-    value/found lanes.
+    value/found lanes, which come back final: no epilogue runs.
+
+    Nothing of the whole index's size is built for a lookup.  The rank ->
+    BFS map and the sorted view (the ordered epilogues' gathers over the
+    whole snapshot, jit constants) are built when the first op that reads
+    them is served.  The runner also carries the write path on the mesh:
+    ``ingest`` classifies a write chunk with the sharded descent and lands
+    it in a REPLICATED buffer, and ``compact`` stages the merge through
+    the mesh's first device and returns the merged snapshot to the host.
     """
-    sorted_cache: list = []  # built on the first delta call only
+    q_sharding = NamedSharding(mesh, P(axis))
+    replicated = NamedSharding(mesh, P())
+    sorted_cache: list = []  # the whole sorted view, built on first use
 
     def _sorted_view():
         if not sorted_cache:
-            sorted_cache.append((tree.keys[rank_to_bfs], tree.values[rank_to_bfs]))
+            r2b = tree_lib.rank_to_bfs_indices(tree.height)
+            sorted_cache.append((np.asarray(tree.keys)[r2b], np.asarray(tree.values)[r2b]))
         return sorted_cache[0]
 
     # Per-op epilogues, jitted once per (op, k, delta?) so steady-state
     # chunks replay cached programs: run eagerly, every sentinel/arange/
     # n_real constant they mix with device results would ride host->device
     # again on EVERY chunk (the retrace/transfer gate fails exactly there).
-    # The snapshot constants (sorted view, rank map) fold at compile time.
+    # The snapshot constants (sorted view, rank map) fold at compile time:
+    # host arrays, made jnp constants inside the trace.
     epilogues: dict = {}
 
     def _epilogue(op: str, k: int, with_delta: bool):
         key = (op, k if op == "range_scan" else None, with_delta)
         if key not in epilogues:
             if with_delta:
-                # Materialized OUTSIDE the trace: caching a gather computed
-                # under jit would leak tracers into sorted_cache.
-                sorted_keys, sorted_values = _sorted_view()
+                host_sorted = _sorted_view()
             if op in plans_lib.RANGE_OPS:
                 def _split(res):
                     # lo||hi concatenated descent (DESIGN.md §6) splits
@@ -172,19 +194,23 @@ def _make_query_runner(
                     def fn(res, delta):
                         r_lo, r_hi = _split(res)
                         return delta_lib.range_epilogue(
-                            op, sorted_keys, sorted_values, tree.n_real,
+                            op, *map(jnp.asarray, host_sorted), tree.n_real,
                             delta, r_lo, r_hi, k=k,
                         )
                 else:
+                    host_r2b = tree_lib.rank_to_bfs_indices(tree.height)
+
                     def fn(res):
                         r_lo, r_hi = _split(res)
+                        full = TreeData(jnp.asarray(tree.keys), jnp.asarray(tree.values),
+                                        tree.height, tree.n_real)
                         return plans_lib.range_epilogue(
-                            op, tree, rank_to_bfs, r_lo, r_hi, k=k
+                            op, full, jnp.asarray(host_r2b), r_lo, r_hi, k=k
                         )
             elif with_delta:
                 def fn(q, res, delta):
                     return delta_lib.point_epilogue(
-                        op, q, res, sorted_keys, sorted_values, tree.n_real,
+                        op, q, res, *map(jnp.asarray, host_sorted), tree.n_real,
                         delta,
                     )
             else:
@@ -193,25 +219,64 @@ def _make_query_runner(
             epilogues[key] = jax.jit(fn)
         return epilogues[key]
 
+    def put(queries) -> jax.Array:
+        """A query batch placed straight from the host onto the mesh,
+        sharded over ``axis`` (an array already placed so stays as it is)."""
+        if not isinstance(queries, jax.Array):
+            queries = np.asarray(queries, np.int32)
+        elif queries.dtype != jnp.int32:
+            queries = queries.astype(jnp.int32)
+        return jax.device_put(queries, q_sharding)
+
     def run(op: str, queries, queries_hi=None, *, k: int = 8, delta=None):
         plans_lib.validate_op(op, queries_hi is not None)
         d_ops = None if delta is None else _delta_operands(delta)
-        if op == "lookup" and lookup is not None:
+        q = put(queries)
+        if op == "lookup":
             # delta-hit > tombstone > tree-hit resolves in-program, so the
             # membership columns come back final either way.
-            return lookup(jnp.asarray(queries, jnp.int32), d_ops)
+            if lookup is not None:
+                return lookup(q, d_ops)
+            res = descend(q, d_ops)
+            return res.value, res.found
         if op in plans_lib.RANGE_OPS:
-            lo = jnp.asarray(queries, jnp.int32)
-            hi = jnp.asarray(queries_hi, jnp.int32)
-            both = jnp.concatenate([lo, hi])
+            both = jnp.concatenate([q, put(queries_hi)])
             res = descend(both, d_ops)
             epi = _epilogue(op, k, delta is not None)
             return epi(res, delta) if delta is not None else epi(res)
-        q = jnp.asarray(queries, jnp.int32)
         res = descend(q, d_ops)
         epi = _epilogue(op, k, delta is not None)
         return epi(q, res, delta) if delta is not None else epi(q, res)
 
+    @functools.partial(jax.jit, out_shardings=replicated)
+    def _land(delta, keys, values, deletes, valid, found, rank):
+        B = keys.shape[0]
+        return delta_lib.ingest(delta, keys, values, deletes, valid, found[:B], rank[:B])
+
+    def ingest(delta, keys, values, deletes, valid):
+        """One write chunk into the buffer: classified against the sharded
+        snapshot, landed in a buffer replicated on every device."""
+        pad = (-keys.shape[0]) % mesh.shape[axis]
+        q = keys if not pad else jnp.concatenate([keys, jnp.zeros((pad,), jnp.int32)])
+        res = descend(put(q))
+        return _land(delta, keys, values, deletes, valid, res.found, res.rank)
+
+    def compact(snapshot: TreeData, delta) -> TreeData:
+        """Merge the buffer into a fresh snapshot on the mesh's first device
+        (the whole snapshot passes through it for the merge) and return the
+        merged snapshot to the host, whence its shards are placed."""
+        dev = mesh.devices.flat[0]
+        staged = TreeData(
+            jax.device_put(snapshot.keys, dev), jax.device_put(snapshot.values, dev),
+            snapshot.height, snapshot.n_real,
+        )
+        return on_host(delta_lib.compact(staged, jax.device_put(delta, dev)))
+
+    run.put = put
+    # a fresh buffer starts where every ingest leaves it: replicated
+    run.empty_delta = lambda capacity: jax.device_put(delta_lib.empty(capacity), replicated)
+    run.ingest = ingest
+    run.compact = compact
     return run
 
 
@@ -251,7 +316,6 @@ def make_distributed_query(
     reg_n = (1 << max(split_level, 1)) - 1
     reg_keys = jax.device_put(tree.keys[:reg_n], NamedSharding(mesh, P()))
     reg_vals = jax.device_put(tree.values[:reg_n], NamedSharding(mesh, P()))
-    rank_to_bfs = jnp.asarray(tree_lib.rank_to_bfs_indices(tree.height))
 
     def _one_round(queries, dest, active, sub_k, sub_v, cap):
         """dispatch -> all_to_all -> local ordered descent -> all_to_all back."""
@@ -348,15 +412,25 @@ def make_distributed_query(
         return programs[with_delta]
 
     def _descend(queries, d_ops=None) -> plans_lib.OrderedResult:
-        q = jax.device_put(
-            jnp.asarray(queries, jnp.int32), NamedSharding(mesh, P(axis))
-        )
         extra = tuple(d_ops) if d_ops is not None else ()
         return plans_lib.OrderedResult(
-            *_program(bool(extra))(q, sub_keys, sub_vals, *extra)
+            *_program(bool(extra))(queries, sub_keys, sub_vals, *extra)
         )
 
-    run = _make_query_runner(_descend, tree, rank_to_bfs)
+    def routed_slots(lanes: int) -> int:
+        """Send-buffer slots the router's ``all_to_all`` carries in one
+        call of ``lanes`` lanes: chips x chips x capacity, per round that
+        every call runs (a capped router's conditional drain round is not
+        counted)."""
+        B = lanes // M
+        if capacity_frac is not None:
+            cap = invariants.capacity_for_trace(B, M, capacity_frac)
+        else:
+            cap = capacity if capacity is not None else B
+        return M * M * cap * (1 + (stall_rounds if capped else 0))
+
+    run = _make_query_runner(_descend, tree, mesh, axis)
+    run.routed_slots = routed_slots
     run.mesh = mesh
     run.capacity = capacity
     run.split_level = split_level
@@ -412,7 +486,6 @@ def make_dup_query(
     """
     keys = jax.device_put(tree.keys, NamedSharding(mesh, P()))
     vals = jax.device_put(tree.values, NamedSharding(mesh, P()))
-    rank_to_bfs = jnp.asarray(tree_lib.rank_to_bfs_indices(tree.height))
 
     def _local(queries, k, v, *d_ops):
         res = plans_lib.descend_phase_ordered(
@@ -461,11 +534,8 @@ def make_dup_query(
         return programs[key]
 
     def _call(body, n_out, queries, d_ops):
-        q = jax.device_put(
-            jnp.asarray(queries, jnp.int32), NamedSharding(mesh, P(axis))
-        )
         extra = tuple(d_ops) if d_ops is not None else ()
-        return _program(body, n_out, bool(extra))(q, keys, vals, *extra)
+        return _program(body, n_out, bool(extra))(queries, keys, vals, *extra)
 
     def _descend(queries, d_ops=None) -> plans_lib.OrderedResult:
         return plans_lib.OrderedResult(*_call(_local, 7, queries, d_ops))
@@ -473,7 +543,8 @@ def make_dup_query(
     def _lookup(queries, d_ops=None):
         return _call(_local_lookup, 2, queries, d_ops)
 
-    run = _make_query_runner(_descend, tree, rank_to_bfs, lookup=_lookup)
+    run = _make_query_runner(_descend, tree, mesh, axis, lookup=_lookup)
+    run.routed_slots = lambda lanes: 0  # no router: each replica serves its own slice
     run.mesh = mesh
     run.device_nodes = stored_nodes_per_device(keys)
     return run

@@ -114,30 +114,30 @@ class BSTEngine:
         self._finalize()
 
     @classmethod
-    def from_tree(cls, tree: TreeData, config: EngineConfig = EngineConfig()):
-        """Wrap an existing immutable snapshot (serving's bulk-update swap)."""
+    def from_tree(cls, tree: TreeData, config: EngineConfig = EngineConfig(), sharded=None):
+        """Wrap an existing immutable snapshot (serving's bulk-update swap);
+        ``sharded`` is the serving layer's sharded reader of it, if any."""
         self = cls.__new__(cls)
         self.config = config
         self.tree = tree
+        self.sharded = sharded
         self._finalize()
         return self
 
     # ------------------------------------------------------------------ build
     def _finalize(self) -> None:
         cfg = self.config
-        self.plan = plans_lib.make_plan(
-            self.tree,
-            strategy=cfg.strategy,
-            n_trees=cfg.n_trees,
-            mapping=cfg.mapping,
-            register_levels=cfg.register_levels,
-            buffer_slack=cfg.buffer_slack,
-        )
+        # Sharded-reader hook (DESIGN.md §9): the serving layer's
+        # ``make_sharded_query`` run over this snapshot, where a mesh is
+        # installed.  Writes then classify through its sharded descent and
+        # compact through it, and the one-chip plan (a whole-index copy on
+        # one device) is not built.  None by default.
+        self.sharded = getattr(self, "sharded", None)
+        self._plan = None if self.sharded is not None else self._make_plan()
         self._query_cache: Dict[Tuple[str, int], callable] = {}
         # Live write path (DESIGN.md §7): a fresh empty buffer per snapshot.
-        self.delta = (
-            delta_lib.empty(cfg.delta_capacity) if cfg.delta_capacity > 0 else None
-        )
+        empty = delta_lib.empty if self.sharded is None else self.sharded.empty_delta
+        self.delta = empty(cfg.delta_capacity) if cfg.delta_capacity > 0 else None
         self._ingest = jax.jit(self._ingest_step) if self.delta is not None else None
         # Host-side occupancy upper bound (<= sum of batch sizes since the
         # last compaction): the compaction trigger never syncs the device
@@ -155,6 +155,25 @@ class BSTEngine:
         # layer installs its own, so compaction time lands in its phase
         # counters and the profiler trace.  No span by default.
         self.span = getattr(self, "span", _no_span)
+
+    def _make_plan(self) -> plans_lib.SearchPlan:
+        cfg = self.config
+        return plans_lib.make_plan(
+            self.tree,
+            strategy=cfg.strategy,
+            n_trees=cfg.n_trees,
+            mapping=cfg.mapping,
+            register_levels=cfg.register_levels,
+            buffer_slack=cfg.buffer_slack,
+        )
+
+    @property
+    def plan(self) -> plans_lib.SearchPlan:
+        """The snapshot's one-chip search plan; a sharded engine builds it
+        only if asked (its reads and writes never are)."""
+        if self._plan is None:
+            self._plan = self._make_plan()
+        return self._plan
 
     # ------------------------------------------------------------------ query
     def query(self, op: str, queries, queries_hi=None, *, k: int = 8):
@@ -261,7 +280,8 @@ class BSTEngine:
                 continue
             if self._pending_writes + m > cap:
                 self.compact()
-            self.delta = self._ingest(
+            ingest = self._ingest if self.sharded is None else self.sharded.ingest
+            self.delta = ingest(
                 self.delta,
                 jnp.asarray(keys[sl]),
                 jnp.asarray(values[sl]),
@@ -329,7 +349,8 @@ class BSTEngine:
         if self.delta is None or self._pending_writes == 0:
             return self.tree
         with self.span("compact"):
-            self.tree = delta_lib.compact(self.tree, self.delta)
+            compact = delta_lib.compact if self.sharded is None else self.sharded.compact
+            self.tree = compact(self.tree, self.delta)
             self.compactions += 1
             self._finalize()
             if self.on_snapshot is not None:

@@ -180,13 +180,18 @@ def eytzinger_from_sorted(
     return bfs_keys, bfs_vals, H, n_real
 
 
-def build_tree(keys: np.ndarray, values: np.ndarray) -> TreeData:
-    """Build a TreeData from (unsorted) unique keys + values."""
+def build_tree(keys: np.ndarray, values: np.ndarray, host: bool = False) -> TreeData:
+    """Build a TreeData from (unsorted) unique keys + values.
+
+    ``host=True`` leaves the arrays in host memory (NumPy): a sharded
+    server places only its shards on the devices (DESIGN.md §9)."""
     keys = np.asarray(keys, dtype=np.int32)
     values = np.asarray(values, dtype=np.int32)
     order = np.argsort(keys, kind="stable")
     k, v, h, n_real = eytzinger_from_sorted(keys[order], values[order])
-    return TreeData(keys=jnp.asarray(k), values=jnp.asarray(v), height=h, n_real=n_real)
+    if not host:
+        k, v = jnp.asarray(k), jnp.asarray(v)
+    return TreeData(keys=k, values=v, height=h, n_real=n_real)
 
 
 def search_reference(tree: TreeData, queries: jax.Array) -> Tuple[jax.Array, jax.Array]:

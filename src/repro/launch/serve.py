@@ -64,6 +64,9 @@ def bst_main(args) -> None:
     )
     print("host ms by phase: " + ", ".join(f"{k} {v * 1e3:.1f}" for k, v in s.phase_s.items())
           + f"; row answers {s.row_answers} of {s.requests} requests")
+    if mesh is not None:
+        print(f"router: {s.routed_slots} routed slots for {s.lanes} lanes, "
+              f"{s.overlapped_chunks} of {s.chunks} chunks overlapped")
 
     # a mixed tail: writes ride the replicated delta buffer on-device
     wk = rng.integers(1, 2**20, args.chunk).astype(np.int32)

@@ -63,11 +63,16 @@ atomically between chunks.  This module is that loop, TPU-native:
     scheduler: the next fixed-shape chunk is formed and dispatched while
     the previous one is still in flight, and the sync point trails one
     chunk behind, so host-side packing overlaps device compute.  The
-    write path is unchanged -- ingest classifies against the local
-    snapshot, and the pending buffer rides every sharded read as four
-    REPLICATED operands folded on-device inside the sharded program; a
-    compaction rebuilds the sharded programs via the engine's
-    ``on_snapshot`` hook before the next read.  ``chunk_size`` must
+    snapshot stays in host memory and each device holds only its shard
+    and the replicated parts; the engine never builds its one-chip plan.
+    Ingest classifies writes with the sharded descent into a write
+    buffer replicated on every device, which rides every sharded read as
+    four operands folded on-device inside the sharded program; a
+    compaction merges through one device and rebuilds the sharded
+    programs via the engine's ``on_snapshot`` hook before the next read.
+    ``ServerStats.routed_slots`` / ``overlapped_chunks`` count the
+    router's send-buffer slots and the overlapped chunks, and span
+    ``shard_put`` times each chunk's sharded put.  ``chunk_size`` must
     divide by the mesh axis size (chunks are always padded full, so no
     unpadded partial chunk can ever reach a sharded program).
 """
@@ -141,6 +146,13 @@ class ServerStats:
     # Requests answered by row views: every request of their op group held
     # one key or one range (see ``BSTServer._unpack``).
     row_answers: int = 0
+    # Sharded mode only.  Send-buffer slots the router's all_to_all
+    # carried, summed over chunks (chips x chips x capacity per round):
+    # beside ``lanes`` it shows the router's padding.
+    routed_slots: int = 0
+    # Chunks dispatched while an earlier chunk of the same stream was still
+    # in flight (the double-buffered scheduler's overlap).
+    overlapped_chunks: int = 0
 
     def op(self, name: str) -> OpStats:
         return self.per_op.setdefault(name, OpStats())
@@ -250,18 +262,26 @@ class BSTServer:
             if config.delta_capacity > 0
             else chunk_size
         )
-        self._install(tree_lib.build_tree(np.asarray(keys), np.asarray(values)))
+        # Sharded, the snapshot stays on the host: only the shards and the
+        # replicated parts are placed on the devices (DESIGN.md §9).
+        self._install(
+            tree_lib.build_tree(np.asarray(keys), np.asarray(values), host=mesh is not None)
+        )
 
     # --------------------------------------------------------------- snapshot
     def _install(self, tree: TreeData) -> None:
-        self._engine = BSTEngine.from_tree(tree, self.config)
-        self._engine.span = self._span  # compaction time lands in phase_s
-        if self.mesh is not None:
-            self._install_sharded(tree)
+        if self.mesh is None:
+            self._engine = BSTEngine.from_tree(tree, self.config)
+        else:
+            tree = dist_lib.on_host(tree)
+            self._engine = BSTEngine.from_tree(
+                tree, self.config, sharded=self._install_sharded(tree)
+            )
             # Compaction can swap the snapshot deep inside apply_ops'
             # chunk loop; the hook rebuilds the sharded programs before
             # any later read can see the stale tree (DESIGN.md §9).
-            self._engine.on_snapshot = self._install_sharded
+            self._engine.on_snapshot = self._reshard
+        self._engine.span = self._span  # compaction time lands in phase_s
         # The fresh engine's jit closes over the new snapshot; re-warm so
         # post-swap chunks (and busy accounting) stay compile-free.
         self._rewarm()
@@ -276,7 +296,8 @@ class BSTServer:
         """A ``_Span`` of this server: ``with self._span("pack"): ...``."""
         return _Span(self, name, ids)
 
-    def _install_sharded(self, tree: TreeData) -> None:
+    def _install_sharded(self, tree: TreeData):
+        """Place ``tree``'s shards and build the sharded reader over them."""
         cfg = self.config
         self._squery = dist_lib.make_sharded_query(
             tree,
@@ -285,6 +306,12 @@ class BSTServer:
             buffer_slack=cfg.buffer_slack,
             use_kernel=cfg.use_kernel,
         )
+        return self._squery
+
+    def _reshard(self, tree: TreeData) -> None:
+        """The engine's snapshot-swap hook in sharded mode: the new
+        snapshot's reader also takes the engine's writes."""
+        self._engine.sharded = self._install_sharded(dist_lib.on_host(tree))
 
     @property
     def snapshot(self) -> TreeData:
@@ -316,6 +343,9 @@ class BSTServer:
         threads its own buffer internally."""
         if self._squery is not None:
             kw = {"delta": self._engine.delta} if self._engine.delta is not None else {}
+            with self._span("shard_put"):  # the chunk's host->device put
+                a = self._squery.put(a)
+                b = self._squery.put(b) if op in RANGE_OPS else None
             if op in RANGE_OPS:
                 res = self._squery(op, a, b, k=self.scan_k, **kw)
             else:
@@ -698,12 +728,16 @@ class BSTServer:
                 real = min(self.chunk_size, B - r_lo)
                 found += int(columns[1][r_lo : r_lo + real].sum())
 
+        lanes_per_call = self.chunk_size * (2 if op in RANGE_OPS else 1)
+        routed = self._squery.routed_slots(lanes_per_call)
         t0 = time.perf_counter()
         for lo in range(0, a.size, self.chunk_size):
             sl = slice(lo, lo + self.chunk_size)
             chunk = first + n_chunks
+            self.stats.overlapped_chunks += bool(inflight)
             with self._span("dispatch", chunk=chunk, lanes=min(self.chunk_size, B - lo)):
                 res = self._query_chunk(op, a[sl], None if b is None else b[sl])
+            self.stats.routed_slots += routed
             inflight.append((sl, lo, res, chunk))
             n_chunks += 1
             if len(inflight) > 1:  # depth-2 pipeline: retire the older chunk

@@ -239,7 +239,8 @@ def test_write_span_through_a_compaction_records_ingest_compact_rewarm():
 
 def test_sharded_server_records_the_same_spans(multi_device_host):
     """The double-buffered scheduler over four forced host devices records
-    the single-chip loop's span names, write path included."""
+    the single-chip loop's span names, write path included, and its own
+    sharded put of each chunk (``shard_put``)."""
     out = multi_device_host("""
         from repro.core import distributed as D
         from repro.core.engine import EngineConfig
@@ -259,7 +260,7 @@ def test_sharded_server_records_the_same_spans(multi_device_host):
         print(sorted(s.phase_s))
     """, devices=4, timeout=900)
     names = out.strip().splitlines()[-1]
-    assert names == str(sorted(READ_SPANS | {"ingest", "compact", "rewarm"}))
+    assert names == str(sorted(READ_SPANS | {"ingest", "compact", "rewarm", "shard_put"}))
 
 
 READ_OPS = ("lookup", "predecessor", "successor", "range_count", "range_scan")
