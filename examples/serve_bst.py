@@ -99,7 +99,7 @@ def main():
         print(f"{op:12s} {st.served:10d} {st.chunks:7d} {st.busy_s * 1e3:10.1f}")
     s = srv.stats
     print("host ms by phase: " + ", ".join(f"{k} {v * 1e3:.1f}" for k, v in s.phase_s.items())
-          + f"; row answers {s.row_answers} of {s.requests} requests")
+          + f"; row answers {s.row_answers} of {s.requests} requests, {s.op_runs} op runs")
 
     # ---- live write path: delta-buffered updates, compaction, no rebuilds
     cfg = dataclasses.replace(PAPER_CONFIGS["Hyb8q"], delta_capacity=4096)
@@ -125,7 +125,7 @@ def main():
         f"on device, {s.compactions} compaction(s), 0 rebuilds"
     )
     print("  host ms by phase: " + ", ".join(f"{k} {v * 1e3:.1f}" for k, v in s.phase_s.items())
-          + f"; row answers {s.row_answers} of {s.requests} requests")
+          + f"; row answers {s.row_answers} of {s.requests} requests, {s.op_runs} op runs")
     v, f = srv.lookup(wk[half + 1 : half + 9])
     print(f"  post-write lookups: found {int(np.asarray(f).sum())}/8 fresh keys")
 

@@ -63,7 +63,7 @@ def bst_main(args) -> None:
         f"({s.served / dt:.0f} ops/s, {s.found} found, {s.chunks} chunks)"
     )
     print("host ms by phase: " + ", ".join(f"{k} {v * 1e3:.1f}" for k, v in s.phase_s.items())
-          + f"; row answers {s.row_answers} of {s.requests} requests")
+          + f"; row answers {s.row_answers} of {s.requests} requests, {s.op_runs} op runs")
     if mesh is not None:
         print(f"router: {s.routed_slots} routed slots for {s.lanes} lanes, "
               f"{s.overlapped_chunks} of {s.chunks} chunks overlapped")
@@ -81,7 +81,7 @@ def bst_main(args) -> None:
     )
     s = srv.stats
     print("host ms by phase, all drains: " + ", ".join(f"{k} {v * 1e3:.1f}" for k, v in s.phase_s.items())
-          + f"; row answers {s.row_answers} of {s.requests} requests")
+          + f"; row answers {s.row_answers} of {s.requests} requests, {s.op_runs} op runs")
 
 
 def main(argv=None):

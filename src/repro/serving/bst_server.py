@@ -14,16 +14,21 @@ atomically between chunks.  This module is that loop, TPU-native:
     into fixed ``chunk_size`` engine calls (the jit shape), padding only the
     final partial chunk per op; per-request results are sliced back out, so
     padded lanes never leak into answers or accounting;
-  * **columnar queue** -- the pending queue is three parallel lists (op,
-    keys or range lows, range highs or write values), not one object per
-    request, and tickets are implicit: request ``i`` of the queue holds
-    ticket ``first + i``.  ``submit`` queues an int32 vector as it is (no
-    copy, no conversion call).  The drain tests the op column in C: a queue
-    of one op kind is one read span and one group, whose keys are one
-    ``np.concatenate``.  Where every request of a group holds one key (or
-    one range), its answers are the result columns' rows, built in C as
-    shape-``(1, ...)`` views (``ServerStats.row_answers`` counts them);
-    any other group is sliced request by request;
+  * **op-run queue** -- the pending queue is one list of each request's
+    keys or range lows, the op RUNS (each run's start and op, one run per
+    change of op) and, by queue position, the second operands of the
+    requests that have one (range highs, write values); tickets are
+    implicit: request
+    ``i`` of the queue holds ticket ``first + i``.  A ``submit`` that
+    continues its op's run costs the int32 check and one list append (an
+    int32 vector is queued as it is: no copy, no conversion call, no
+    counter); a new run, and every other request kind, takes ``_enqueue``.
+    The drain reads the runs: a queue of one run is one read span and one
+    group, whose keys are one ``np.concatenate``.  Where every request of
+    a group holds one key (or one range), its answers are the result
+    columns' rows, built in C as shape-``(1, ...)`` views
+    (``ServerStats.row_answers`` counts them); any other group is sliced
+    request by request;
   * **pluggable engine config** -- any ``EngineConfig`` (strategy, mapping,
     kernel/reference path) serves the same request API;
   * **live write path** (DESIGN.md §7) -- with
@@ -103,6 +108,8 @@ POINT_OPS = tuple(op for op in plans_lib.QUERY_OPS if op not in RANGE_OPS)
 WRITE_OPS = ("write", "delete")
 _WRITE_SET = frozenset(WRITE_OPS)
 _INT32 = np.dtype(np.int32)
+# ``BSTServer._tail`` where no point-op run is open: no caller's op is it.
+_NO_TAIL = object()
 
 
 @dataclasses.dataclass
@@ -120,12 +127,17 @@ class OpStats:
     lanes: int = 0
 
 
-@dataclasses.dataclass(slots=True)  # slots: submit() bumps two counters per request
+@dataclasses.dataclass
 class ServerStats:
-    """Cumulative serving counters (reset with ``BSTServer.reset_stats``)."""
+    """Cumulative serving counters (reset with ``BSTServer.reset_stats``).
 
-    requests: int = 0  # submit() calls
-    submitted: int = 0  # keys/ranges accepted
+    ``requests``, ``submitted`` and ``op_runs`` count DRAINED requests:
+    each drain adds its queue's totals once, so a request still pending is
+    in none of them and ``submit`` bumps no counter.
+    """
+
+    requests: int = 0  # requests drained
+    submitted: int = 0  # keys/ranges of the drained requests
     served: int = 0  # keys/ranges/write-ops answered
     found: int = 0  # lookup hits, accumulated per chunk
     chunks: int = 0  # engine invocations
@@ -146,6 +158,11 @@ class ServerStats:
     # Requests answered by row views: every request of their op group held
     # one key or one range (see ``BSTServer._unpack``).
     row_answers: int = 0
+    # Op runs of the drained requests: one per request that began a run
+    # (the first of its drain, or one whose op differs from the request
+    # before it).  The other ``requests - op_runs`` were queued by
+    # ``submit``'s one-append path.
+    op_runs: int = 0
     # Sharded mode only.  Send-buffer slots the router's all_to_all
     # carried, summed over chunks (chips x chips x capacity per round):
     # beside ``lanes`` it shows the router's padding.
@@ -191,17 +208,26 @@ class _Span:
         self._annotation.__exit__(*exc)
 
 
-def _order_spans(ops: List[str]) -> List[Tuple[int, int]]:
-    """``[lo, hi)`` bounds of the maximal runs of reads and of writes in
-    ``ops``, in order.  A queue of one op kind, or of reads alone, is one
-    run, found by C-level scans; any other is walked request by request."""
-    n = len(ops)
-    if ops.count(ops[0]) == n or _WRITE_SET.isdisjoint(ops):
-        return [(0, n)]
-    bounds = [
-        i for i in range(1, n) if (ops[i] in WRITE_OPS) != (ops[i - 1] in WRITE_OPS)
+def _order_spans(starts: List[int], ops: List[str], n: int) -> List[Tuple[int, int, list, list]]:
+    """``(lo, hi, starts, ops)`` of the maximal spans of reads and of writes
+    in a queue of ``n`` requests whose op runs start at ``starts`` with ops
+    ``ops``, in order: each span's bounds, and its runs' starts and ops.  A
+    queue of reads alone is one span, found in C; any other is walked run
+    by run."""
+    if _WRITE_SET.isdisjoint(ops):
+        return [(0, n, starts, ops)]
+    m = len(ops)
+    cuts = [j for j in range(1, m) if (ops[j] in _WRITE_SET) != (ops[j - 1] in _WRITE_SET)]
+    cuts = [0] + cuts + [m]
+    return [
+        (starts[j], starts[k] if k < m else n, starts[j:k], ops[j:k])
+        for j, k in zip(cuts, cuts[1:])
     ]
-    return list(zip([0] + bounds, bounds + [n]))
+
+
+def _run_lengths(starts: List[int], hi: int) -> np.ndarray:
+    """Requests per run, for runs starting at ``starts`` and ending at ``hi``."""
+    return np.diff(np.fromiter(starts, np.intp, len(starts)), append=hi)
 
 
 class BSTServer:
@@ -245,13 +271,18 @@ class BSTServer:
             invariants.check_chunk_divides(chunk_size, mesh.shape[axis], axis)
         self.stats = ServerStats()
         self._open_spans: List[_Span] = []
-        # The pending queue, one list per field of a request: its op, its
-        # keys (point / write / delete ops) or range lows, and its range
-        # highs or write values (None otherwise).  Request i holds ticket
-        # ``_first_ticket + i``.
-        self._ops: List[str] = []
+        # The pending queue: each request's keys (point / write / delete
+        # ops) or range lows; the op runs, a start and an op appended where
+        # a request's op differs from the one before it; and the range
+        # highs or write values by queue position, for the requests that
+        # have them.  Request i holds ticket ``_first_ticket + i``.
+        # ``_tail`` is the last run's op where ``submit`` may continue it
+        # (a point op), else ``_NO_TAIL``.
         self._a: List[np.ndarray] = []
-        self._b: List[Optional[np.ndarray]] = []
+        self._starts: List[int] = []
+        self._run_ops: List[str] = []
+        self._b: Dict[int, np.ndarray] = {}
+        self._tail: object = _NO_TAIL
         self._first_ticket = 0
         self._oldest_t = 0.0  # enqueue time of the oldest pending request
         self._warm_ops: Tuple[str, ...] = ()
@@ -401,15 +432,19 @@ class BSTServer:
         ``op`` is one of ``lookup`` (values, found), ``predecessor`` /
         ``successor`` (keys, values, ok) -- DESIGN.md §6 semantics.
         """
-        if op not in POINT_OPS:
-            raise ValueError(f"submit() op must be one of {POINT_OPS}, got {op!r}")
         req = request_keys
         # An int32 vector is what the conversion would return as it is.
         if not (type(req) is np.ndarray and req.dtype is _INT32 and req.ndim == 1):
             req = np.atleast_1d(np.asarray(req, np.int32))
             if req.ndim != 1:
                 raise ValueError("request_keys must be scalar or 1-D")
-        return self._enqueue(op, req, None)
+        if op is self._tail:  # the run goes on; its op was checked at its start
+            a = self._a
+            a.append(req)
+            return self._first_ticket + len(a) - 1
+        if op not in POINT_OPS:
+            raise ValueError(f"submit() op must be one of {POINT_OPS}, got {op!r}")
+        return self._enqueue(op, req, None, op)
 
     def submit_range(self, lo, hi, op: str = "range_count") -> int:
         """Queue a range request over [lo, hi] (inclusive); returns a ticket.
@@ -459,17 +494,23 @@ class BSTServer:
                 " > 0); use apply_updates() for bulk snapshot swaps"
             )
 
-    def _enqueue(self, op: str, a: np.ndarray, b: Optional[np.ndarray]) -> int:
-        ops = self._ops
-        if not ops:
+    def _enqueue(self, op: str, a: np.ndarray, b: Optional[np.ndarray],
+                 tail: object = _NO_TAIL) -> int:
+        """Queue a checked request off ``submit``'s one-append path: start a
+        run where ``op`` differs from the last run's, keep ``b``, and leave
+        ``tail`` as the op ``submit`` may continue."""
+        q = self._a
+        i = len(q)
+        if not i:
             self._oldest_t = time.perf_counter()  # once per drain, not per request
-        ops.append(op)
-        self._a.append(a)
-        self._b.append(b)
-        stats = self.stats
-        stats.requests += 1
-        stats.submitted += a.size
-        return self._first_ticket + len(ops) - 1
+        if not i or self._run_ops[-1] != op:
+            self._starts.append(i)
+            self._run_ops.append(op)
+        q.append(a)
+        if b is not None:
+            self._b[i] = b
+        self._tail = tail
+        return self._first_ticket + i
 
     def pending(self) -> int:
         """Keys/ranges queued but not yet served."""
@@ -494,46 +535,58 @@ class BSTServer:
         final partial chunks are padded, and padded lanes never reach
         results or accounting.
         """
-        ops = self._ops
-        if not ops:
+        a = self._a
+        if not a:
             return {}
-        a, b = self._a, self._b
-        first, n, keys = self._first_ticket, len(ops), sum(map(len, a))
-        self._ops, self._a, self._b = [], [], []
+        starts, ops, b = self._starts, self._run_ops, self._b
+        first, n, keys = self._first_ticket, len(a), sum(map(len, a))
+        self._a, self._starts, self._run_ops, self._b = [], [], [], {}
+        self._tail = _NO_TAIL
         self._first_ticket = first + n
-        self.stats.drains += 1
-        self.stats.queue_wait_s += time.perf_counter() - self._oldest_t
+        stats = self.stats
+        stats.requests += n
+        stats.submitted += keys
+        stats.op_runs += len(ops)
+        stats.drains += 1
+        stats.queue_wait_s += time.perf_counter() - self._oldest_t
 
         out: Dict[int, tuple] = {}
         # The ids link every request, by ticket, to the drain that served it.
         with self._span("drain", first_ticket=first, requests=n, keys=keys):
-            for lo, hi in _order_spans(ops):
-                serve = self._serve_write_span if ops[lo] in WRITE_OPS else self._serve_read_span
-                serve(first + lo, ops[lo:hi], a[lo:hi], b[lo:hi], out)
+            for lo, hi, span_starts, span_ops in _order_spans(starts, ops, n):
+                if span_ops[0] in _WRITE_SET:
+                    lengths = _run_lengths(span_starts, hi).tolist()
+                    each_op = [op for op, m in zip(span_ops, lengths) for _ in range(m)]
+                    self._serve_write_span(first + lo, each_op, a[lo:hi],
+                                           [b.get(i) for i in range(lo, hi)], out)
+                else:
+                    self._serve_read_span(first, lo, hi, span_starts, span_ops, a, b, out)
             # Freeing the served requests costs about as much as packing
             # them: free them inside the span, which then holds all of it.
-            del ops, a, b
+            del a, b, starts, ops
         return out
 
-    def _serve_read_span(self, first: int, ops: List[str], a: list, b: list,
-                         out: Dict[int, tuple]):
-        """One writeless span (request ``i`` holds ticket ``first + i``):
+    def _serve_read_span(self, first: int, lo: int, hi: int, starts: list, ops: list,
+                         a: list, b: Dict[int, np.ndarray], out: Dict[int, tuple]):
+        """Requests ``lo:hi`` of the queue ``a``, a writeless span whose op
+        runs start at ``starts`` (request ``i`` holds ticket ``first + i``):
         requests commute, so pack per op kind."""
         with self._span("pack"):
-            if ops.count(ops[0]) == len(ops):  # one op kind: one group
-                groups = [(ops[0], range(first, first + len(ops)), a, b)]
-            else:
-                by_op: Dict[str, List[int]] = {}
-                for i, op in enumerate(ops):
-                    by_op.setdefault(op, []).append(i)
-                groups = [
-                    (op, [first + i for i in idx], [a[i] for i in idx], [b[i] for i in idx])
-                    for op, idx in by_op.items()
-                ]
+            if len(ops) == 1:  # one run: one group, a slice of the queue
+                groups = [(ops[0], range(first + lo, first + hi),
+                           a if hi - lo == len(a) else a[lo:hi], range(lo, hi))]
+            else:  # the runs of each op kind, expanded to requests in C
+                lengths = _run_lengths(starts, hi)
+                run_ops = np.array(ops, dtype=object)
+                groups = []
+                for op in dict.fromkeys(ops):  # in order of first appearance
+                    idx = np.flatnonzero(np.repeat(run_ops == op, lengths)) + lo
+                    at = idx.tolist()
+                    groups.append((op, (idx + first).tolist(), list(map(a.__getitem__, at)), at))
             streams = [
                 (op, tickets, lows, np.concatenate(lows),
-                 np.concatenate(highs) if op in RANGE_OPS else None)
-                for op, tickets, lows, highs in groups
+                 np.concatenate(list(map(b.__getitem__, at))) if op in RANGE_OPS else None)
+                for op, tickets, lows, at in groups
             ]
         for op, tickets, lows, a_all, b_all in streams:
             columns = self._serve_stream(op, a_all, b_all)

@@ -272,7 +272,8 @@ def _drain_script(shape: str, keys, seed: int = 0):
     arrays and as scalars, over several chunks; ``one-key-every-op``: one
     key or one range each, every read op in one span; ``two-and-none``:
     lookups of two keys and of none in turn, as many keys as requests;
-    ``mixed``: scalar, one-key, multi-key and empty requests of every read
+    ``alternating``: one-key lookups and predecessors in turn, an op run
+    each; ``mixed``: scalar, one-key, multi-key and empty requests of every read
     op, with writes and deletes between them."""
     rng = np.random.default_rng(seed)
     pool = np.concatenate([keys, keys + 1])
@@ -295,6 +296,9 @@ def _drain_script(shape: str, keys, seed: int = 0):
         return [read(READ_OPS[i % 5], "scalar" if i % 7 == 0 else "one") for i in range(60)]
     if shape == "two-and-none":
         return [read("lookup", ("two", "empty")[i % 2]) for i in range(40)]
+    if shape == "alternating":
+        return [read(("lookup", "predecessor")[i % 2], "scalar" if i % 7 == 0 else "one")
+                for i in range(60)]
     script = []
     for i in range(80):
         if i % 9 == 4:
@@ -415,3 +419,102 @@ def test_bad_requests_raise_and_queue_nothing(name):
     assert srv.pending() == 0 and srv.stats.requests == 0
     assert srv.drain() == {}
     assert srv.submit(keys[:2]) == 0  # the ticket count did not move
+
+
+_SUBMIT_OP = {"submit_write": "write", "submit_delete": "delete"}
+
+
+def _op_changes(script) -> int:
+    """Requests of ``script`` whose op differs from the request before it."""
+    ops = [_SUBMIT_OP.get(m) or args[-1] for m, args in script]
+    return sum(x != y for x, y in zip(ops, ops[1:]))
+
+
+@pytest.mark.parametrize("shape", ["one-key", "alternating", "mixed"])
+def test_op_run_queue_answers_and_counts_per_drain(shape):
+    """Queues of one op run, of alternating point ops, and of reads and
+    range ops between writes and deletes answer as each request alone; the
+    queue counts nothing while it fills, and each drain adds its requests,
+    their keys and its op runs (one, plus one per change of op)."""
+    keys, values = make_tree_data(500, seed=8)
+    cfg = EngineConfig(strategy="hrz", delta_capacity=64, delta_high_water=48)
+
+    def make():
+        return BSTServer(keys, values, cfg, chunk_size=64)
+
+    script = _drain_script(shape, keys, seed=3)
+    check_columnar_drain(make, script)
+    srv = make()
+    n_keys = sum(int(np.size(args[0])) for _, args in script)
+    for r in range(1, 3):
+        for m, args in script:
+            getattr(srv, m)(*args)
+        s = srv.stats
+        assert (s.requests, s.submitted, s.op_runs) == ((r - 1) * len(script),
+                                                        (r - 1) * n_keys,
+                                                        (r - 1) * (1 + _op_changes(script)))
+        srv.drain()
+        assert (s.requests, s.submitted, s.op_runs) == (r * len(script), r * n_keys,
+                                                        r * (1 + _op_changes(script)))
+    if shape == "one-key":
+        assert srv.stats.op_runs == 2  # one run per drain
+
+
+@pytest.mark.parametrize("order", ["continues-the-run", "returns-to-an-earlier-op"])
+def test_op_equal_to_a_run_but_not_the_same_object_joins_its_group(order):
+    """An op string equal to a queued op, but another object, joins that
+    op's run where it continues it, and its group (one engine call per op)
+    in either case."""
+    keys, values = make_tree_data(255, seed=4)
+    srv = BSTServer(keys, values, chunk_size=64)
+    literal, lookup = "lookup", "".join(["look", "up"])
+    assert lookup == literal and lookup is not literal
+    ops = {
+        "continues-the-run": ["lookup", lookup, "lookup", lookup, "predecessor"],
+        "returns-to-an-earlier-op": ["lookup", "predecessor", lookup, "predecessor", lookup],
+    }[order]
+    reqs = [keys[i : i + 1] for i in range(len(ops))]
+    tickets = [srv.submit(q, op) for q, op in zip(reqs, ops)]
+    assert tickets == list(range(len(ops)))
+    out = srv.drain()
+    for t, q, op in zip(tickets, reqs, ops):
+        want = srv.engine.query(op, q)
+        want = want if isinstance(want, tuple) else (want,)
+        assert len(out[t]) == len(want)
+        for got, w in zip(out[t], want):
+            np.testing.assert_array_equal(got, np.asarray(w))
+    s = srv.stats
+    assert s.op_runs == 1 + sum(x != y for x, y in zip(ops, ops[1:]))
+    assert {op: st.chunks for op, st in s.per_op.items()} == {"lookup": 1, "predecessor": 1}
+    assert s.row_answers == len(ops)  # one group per op, every request one key
+
+
+@pytest.mark.parametrize("run", ["lookup", "range_count"])
+@pytest.mark.parametrize("name", sorted(_BAD_REQUESTS))
+def test_bad_request_mid_run_queues_nothing(name, run):
+    """A request rejected while a run of one-key lookups or of ranges is
+    open leaves the queue, the run and the next ticket as they were: the
+    drain answers as a server that never saw it."""
+    keys, values = make_tree_data(100, seed=2)
+
+    def make():
+        return BSTServer(keys, values, EngineConfig(delta_capacity=64), chunk_size=64)
+
+    def good(srv, i):
+        k = keys[i : i + 1]
+        return srv.submit(k) if run == "lookup" else srv.submit_range(k, k + 5)
+
+    srv, clean = make(), make()
+    assert [good(srv, i) for i in range(3)] == [0, 1, 2]
+    method, args = _BAD_REQUESTS[name]
+    with pytest.raises(ValueError):
+        getattr(srv, method)(*args)
+    assert srv.pending() == 3
+    assert good(srv, 3) == 3  # the ticket count did not move
+    assert [good(clean, i) for i in range(4)] == [0, 1, 2, 3]
+    out, want = srv.drain(), clean.drain()
+    assert sorted(out) == sorted(want)
+    for t in want:
+        for got, w in zip(out[t], want[t], strict=True):
+            np.testing.assert_array_equal(got, w)
+    assert (srv.stats.requests, srv.stats.op_runs, srv.stats.row_answers) == (4, 1, 4)
