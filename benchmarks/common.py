@@ -1,4 +1,4 @@
-"""Shared benchmark utilities: timing + CSV row protocol.
+"""Shared benchmark utilities: the CSV row protocol.
 
 Every benchmark module exposes ``run() -> list[Row]``; benchmarks/run.py
 prints them as ``name,us_per_call,derived`` CSV (one per paper table/figure).
@@ -7,10 +7,6 @@ prints them as ``name,us_per_call,derived`` CSV (one per paper table/figure).
 from __future__ import annotations
 
 import dataclasses
-import time
-from typing import Callable, List, Optional
-
-import jax
 
 
 @dataclasses.dataclass
@@ -21,18 +17,3 @@ class Row:
 
     def csv(self) -> str:
         return f"{self.name},{self.us_per_call:.2f},{self.derived}"
-
-
-def time_fn(fn: Callable, *args, warmup: int = 2, iters: int = 5) -> float:
-    """Median wall time per call in microseconds (block_until_ready)."""
-    for _ in range(warmup):
-        out = fn(*args)
-        jax.block_until_ready(out)
-    times = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        out = fn(*args)
-        jax.block_until_ready(out)
-        times.append(time.perf_counter() - t0)
-    times.sort()
-    return times[len(times) // 2] * 1e6

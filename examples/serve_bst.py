@@ -20,8 +20,8 @@ hybrid engine: the tree vertically partitioned over a (data, model) mesh,
 keys routed by the queue-mapped all_to_all (8 simulated devices), serving
 the same ``query(op, ...)`` contract.  The final section scales the SERVER
 itself out (DESIGN.md §9): ``BSTServer(mesh=...)`` routes every chunk
-through the strategy's shard_map-lowered plan behind the async
-double-buffered scheduler, live writes included -- the pending delta
+through the strategy's shard_map-lowered plan in the server's depth-2
+chunk loop, live writes included -- the pending delta
 buffer rides each sharded read as replicated operands and compactions
 rebuild the sharded programs mid-service.
 """
@@ -176,7 +176,7 @@ def main():
             print(f"  {'':22s} predecessor ok for {int(np.asarray(ok).sum())} keys")
 
     # ---- sharded serving: the server itself over the mesh (DESIGN.md §9)
-    print("\nsharded BSTServer (8 devices, double-buffered scheduler):")
+    print("\nsharded BSTServer (8 devices, depth-2 chunk loop):")
     print(f"{'strategy':10s} {'keys/s':>12s} {'chunks':>7s} {'found':>10s}")
     n_srv = max(args.chunk * 4, args.requests // 4)
     srv_stream = rng.choice(keys, n_srv).astype(np.int32)

@@ -2,8 +2,8 @@
 
 Thin shim over ``python -m repro.analysis`` that pins the repo root and
 ``src`` path so the job runs from any cwd.  All behavior (passes, flags,
-exit-code contract) lives in ``repro.analysis.__main__``; the report
-helper it finishes through is the same one ``check_bench.py`` uses.
+exit-code contract) lives in ``repro.analysis.__main__``, which finishes
+through ``repro.analysis.report.gate``.
 """
 
 from __future__ import annotations

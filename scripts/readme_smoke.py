@@ -3,7 +3,7 @@
 CI runs this after the tier-1 step: every ``PYTHONPATH=src python ...`` line
 inside a fenced ```bash block is executed from the repo root and must exit
 0.  The pytest line is skipped (tier-1 already ran it as its own job step);
-everything else -- quickstart, benchmarks, serving -- runs for real, so a
+everything else -- quickstart, paper figures, serving -- runs for real, so a
 README command that stops working fails the job.
 
     python scripts/readme_smoke.py [README.md]
@@ -33,10 +33,9 @@ def runnable_commands(readme: Path) -> list[str]:
             if not line.startswith("PYTHONPATH=src python"):
                 continue  # pip installs etc. are environment setup, and
                 # chip_smoke.py needs a TPU: it refuses the CPU by design
-            if "pytest" in line or "benchmarks.run" in line:
-                continue  # tier-1 and the benchmark suite run as their own
-                # CI steps (same commands); re-running them here would only
-                # double the job's wall clock
+            if "pytest" in line:
+                continue  # tier-1 runs as its own CI step (same command);
+                # re-running it here would only double the job's wall clock
             cmds.append(line)
     return cmds
 

@@ -16,7 +16,7 @@ MEASURED phase under ``runtime.compile_watch()`` +
   * zero implicit transfers: the ``transfer_guard`` raises on any
     unplanned host->device movement, and the sanctioned ``device_fetch``
     count must equal the drain's exact retire budget (one fetch per read
-    chunk -- ``BSTServer._fill_columns`` -- and nothing else);
+    chunk -- ``BSTServer._retire`` -- and nothing else);
   * zero compactions: the config pins ``delta_high_water`` to the
     capacity and writes far fewer entries, so the measured phase never
     pays the allowlisted one-sync-per-compaction.

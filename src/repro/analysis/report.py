@@ -1,11 +1,10 @@
-"""Violation records + the one exit-code/report helper every gate shares.
+"""Violation records + the exit-code/report helper of the static checks.
 
-``scripts/check_bench.py`` and ``scripts/check_static.py`` (and the
-``python -m repro.analysis`` CLI behind it) all finish through ``gate()``:
-collect failures into a list, print what passed, and exit non-zero with
-the full failure inventory -- never fail on the first finding, so one CI
-run shows every violation.  ``write_json`` emits the machine-readable
-report CI uploads as an artifact alongside the BENCH json.
+``python -m repro.analysis`` (and ``scripts/check_static.py``, its CI
+form) finishes through ``gate()``: collect failures into a list, print
+what passed, and exit non-zero with the full failure inventory -- never
+fail on the first finding, so one CI run shows every violation.  ``write_json`` emits the machine-readable
+report CI uploads as an artifact.
 """
 
 from __future__ import annotations

@@ -5,7 +5,7 @@ MoE 8e top-2, sliding window 4096 [arXiv:2401.04088; hf].
 
 Expert dispatch uses the paper's queue mapping by default
 (moe_dispatch="queue"); "direct" selects the position-mapped variant for
-the Fig.5-style drop-rate comparison (benchmarks/moe_dispatch_bench.py).
+the Fig.5-style drop-rate comparison (examples/moe_dispatch.py).
 """
 
 from repro.models.config import ModelConfig

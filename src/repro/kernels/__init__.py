@@ -5,9 +5,8 @@
                       the hybrid configuration runs route + queue/direct
                       dispatch + stall-round replay in the same body (§8)
   queue_dispatch   -- the paper's queue-mapped buffers as a standalone
-                      kernel (prefix-sum compaction; used by the MoE
-                      dispatch benchmarks -- the BST hybrid path now
-                      dispatches inside the forest kernel itself)
+                      kernel (prefix-sum compaction; the BST hybrid path
+                      now dispatches inside the forest kernel itself)
   flash_attention  -- LM substrate hot-spot (32k prefill cells)
 
 Each has a pure-jnp oracle in ref.py and a jitted wrapper in ops.py.

@@ -18,7 +18,7 @@ its dispatch executes INSIDE the forest search kernel
 (``bst_search._dispatch_lanes``, the same labeling arithmetic without a
 materialized buffer image, because the lanes never move).  This standalone
 kernel remains the buffer-image primitive for workloads that do move
-items -- the MoE dispatch benchmarks and the buffer-semantics tests.
+items -- the buffer-semantics tests.
 """
 
 from __future__ import annotations

@@ -7,7 +7,7 @@ LM mode -- batched greedy decoding with prefill + KV cache:
 BST mode -- the paper's accelerator served through the Pallas forest
 kernel over every device JAX sees.  With more than one device,
 ``BSTServer(mesh=...)`` routes fixed-shape chunks through the strategy's
-shard_map-lowered plan behind the async double-buffered scheduler, with
+shard_map-lowered plan in the server's depth-2 chunk loop, with
 live writes riding the replicated delta buffer:
   PYTHONPATH=src python -m repro.launch.serve --bst --bst-strategy hyb \
       --requests 100000 --chunk 8192

@@ -12,7 +12,7 @@ cost throughput.  We expose both of the paper's mappings:
   * ``direct``: slot = token's position-derived index; cheap, but a token can
     be dropped while the expert buffer still has free slots -- exactly the
     spurious-stall behaviour of Fig. 5, surfaced here as a higher drop rate
-    at equal capacity_factor (benchmarks/moe_dispatch_bench.py measures it).
+    at equal capacity_factor (examples/moe_dispatch.py shows it).
 
 Dispatch/combine are einsum-free gather/scatter on (E, C) buffers, which is
 the layout expert-parallel sharding wants: buffer row e lives wherever

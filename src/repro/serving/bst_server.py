@@ -47,9 +47,14 @@ atomically between chunks.  This module is that loop, TPU-native:
     bulk insert/delete and installs a new engine.  Lookups submitted before
     the swap but not yet drained see the new state (drain-before-swap if
     read-your-epoch consistency is required);
-  * **busy accounting** -- per-chunk timing with ``block_until_ready``,
-    found counts accumulated per chunk (not just the final one).  Busy
-    seconds are attributed per op by the engine lanes each request
+  * **one chunk loop** -- every read stream, on one chip or a mesh, runs
+    a depth-2 pipeline: the next fixed-shape chunk is dispatched while
+    the previous one is still in flight, and the sync and fetch trail one
+    chunk behind, so host-side work overlaps device compute
+    (``ServerStats.overlapped_chunks`` counts the overlapped chunks);
+  * **busy accounting** -- busy seconds are the host time inside each
+    chunk's dispatch, sync and fetch spans (the ingest span on the write
+    path).  They are attributed per op by the engine lanes each request
     actually occupied (one per point/write/delete key, two per range
     request -- the lo||hi concatenated descent), so mixed spans cannot
     skew one op's busy time with another op's;
@@ -64,10 +69,7 @@ atomically between chunks.  This module is that loop, TPU-native:
     (``core.distributed.make_sharded_query``: hrz shards the tree by
     subtree behind the all_to_all router, dup replicates the tree and
     splits the chunk, hyb shards the vertical forest and replicates the
-    register layer).  Chunks are served by an async DOUBLE-BUFFERED
-    scheduler: the next fixed-shape chunk is formed and dispatched while
-    the previous one is still in flight, and the sync point trails one
-    chunk behind, so host-side packing overlaps device compute.  The
+    register layer), through the same chunk loop as one chip.  The
     snapshot stays in host memory and each device holds only its shard
     and the replicated parts; the engine never builds its one-chip plan.
     Ingest classifies writes with the sharded descent into a write
@@ -75,11 +77,10 @@ atomically between chunks.  This module is that loop, TPU-native:
     four operands folded on-device inside the sharded program; a
     compaction merges through one device and rebuilds the sharded
     programs via the engine's ``on_snapshot`` hook before the next read.
-    ``ServerStats.routed_slots`` / ``overlapped_chunks`` count the
-    router's send-buffer slots and the overlapped chunks, and span
-    ``shard_put`` times each chunk's sharded put.  ``chunk_size`` must
-    divide by the mesh axis size (chunks are always padded full, so no
-    unpadded partial chunk can ever reach a sharded program).
+    ``ServerStats.routed_slots`` counts the router's send-buffer slots,
+    and span ``shard_put`` times each chunk's sharded put.  ``chunk_size``
+    must divide by the mesh axis size (chunks are always padded full, so
+    no unpadded partial chunk can ever reach a sharded program).
 """
 
 from __future__ import annotations
@@ -139,9 +140,11 @@ class ServerStats:
     requests: int = 0  # requests drained
     submitted: int = 0  # keys/ranges of the drained requests
     served: int = 0  # keys/ranges/write-ops answered
-    found: int = 0  # lookup hits, accumulated per chunk
+    found: int = 0  # lookup hits (padding excluded)
     chunks: int = 0  # engine invocations
-    busy_s: float = 0.0  # time inside the engine (incl. padding lanes)
+    # Host seconds inside the engine calls' dispatch, sync and fetch spans
+    # and the ingest span (incl. padding lanes).
+    busy_s: float = 0.0
     lanes: int = 0  # engine lanes occupied (see OpStats.lanes)
     snapshot_swaps: int = 0  # full-rebuild swaps (the non-delta path)
     updates: int = 0  # write/delete ops absorbed by the delta buffer
@@ -168,7 +171,7 @@ class ServerStats:
     # beside ``lanes`` it shows the router's padding.
     routed_slots: int = 0
     # Chunks dispatched while an earlier chunk of the same stream was still
-    # in flight (the double-buffered scheduler's overlap).
+    # in flight (the chunk loop's overlap; one chip and sharded alike).
     overlapped_chunks: int = 0
 
     def op(self, name: str) -> OpStats:
@@ -693,123 +696,76 @@ class BSTServer:
         ]
 
     def _serve_stream(self, op: str, a: np.ndarray, b: Optional[np.ndarray]):
-        """Run one op's packed stream through fixed-shape engine chunks."""
+        """Run one op's packed stream through fixed-shape engine chunks.
+
+        One depth-2 pipeline, on one chip and on a mesh alike (DESIGN.md
+        §9): chunk ``i+1`` is dispatched while chunk ``i`` is in flight,
+        and each chunk is retired -- ``sync``, then ``fetch`` -- one chunk
+        behind.  ``busy_s`` is the host time inside each chunk's dispatch,
+        sync and fetch spans (one thread: they never overlap, so the sum
+        counts nothing twice).  Padded lanes never reach results or
+        counters.
+        """
         B = a.size
         if B == 0:
             return self._empty_columns(op)
-        pad = (-B) % self.chunk_size
+        size = self.chunk_size
+        pad = (-B) % size
         if pad:
             with self._span("pack"):
                 a = np.pad(a, (0, pad))
                 if b is not None:
                     b = np.pad(b, (0, pad))
-        if self._squery is not None:
-            return self._serve_stream_sharded(op, a, b, B)
+        stats = self.stats
         columns = None
-        for lo in range(0, a.size, self.chunk_size):
-            sl = slice(lo, lo + self.chunk_size)
-            real = min(self.chunk_size, B - lo)  # non-padded lanes this chunk
-            chunk = self.stats.chunks
+        busy = 0.0
+        flight = None  # (chunk, slice, result) of the chunk in flight
+        for chunk, lo in enumerate(range(0, a.size, size), stats.chunks):
+            sl = slice(lo, lo + size)
             t0 = time.perf_counter()
-            with self._span("dispatch", chunk=chunk, lanes=real):
+            with self._span("dispatch", chunk=chunk, lanes=min(size, B - lo)):
                 res = self._query_chunk(op, a[sl], None if b is None else b[sl])
-            with self._span("sync", chunk=chunk):
-                jax.block_until_ready(res)
-            dt = time.perf_counter() - t0
-            # range requests occupy TWO engine lanes each: the lo||hi
-            # concatenated descent (DESIGN.md §6)
-            lanes = real * (2 if op in RANGE_OPS else 1)
-            self.stats.busy_s += dt
-            self.stats.chunks += 1
-            self.stats.lanes += lanes
-            ops = self.stats.op(op)
-            ops.busy_s += dt
-            ops.chunks += 1
-            ops.lanes += lanes
-            with self._span("fetch", chunk=chunk):
-                columns = self._fill_columns(columns, a.size, sl, res)
-            if op == "lookup":
-                # hits accumulated per chunk from the host columns the
-                # retire already paid for -- no extra device sync
-                self.stats.found += int(columns[1][lo : lo + real].sum())
-        self.stats.served += B
-        self.stats.op(op).served += B
+            busy += time.perf_counter() - t0
+            if flight is not None:
+                stats.overlapped_chunks += 1
+                columns, dt = self._retire(columns, a.size, *flight)
+                busy += dt
+            flight = (chunk, sl, res)
+        columns, dt = self._retire(columns, a.size, *flight)
+        busy += dt
+        n_chunks = a.size // size
+        # range requests occupy TWO engine lanes each: the lo||hi
+        # concatenated descent (DESIGN.md §6)
+        per = 2 if op in RANGE_OPS else 1
+        if self._squery is not None:
+            stats.routed_slots += n_chunks * self._squery.routed_slots(size * per)
+        if op == "lookup":
+            stats.found += int(columns[1][:B].sum())
+        for s in (stats, stats.op(op)):
+            s.busy_s += busy
+            s.chunks += n_chunks
+            s.lanes += B * per
+            s.served += B
         return [col[:B] for col in columns]
 
-    def _fill_columns(self, columns, total: int, sl: slice, res: tuple):
-        """Copy one chunk's result tuple into the stream-sized host columns.
+    def _retire(self, columns, total: int, chunk: int, sl: slice, res: tuple):
+        """Wait for one chunk and fetch it into the stream-sized host
+        columns; returns the columns and the host seconds of both spans.
 
         The ONLY place read results cross device->host: one sanctioned
         ``device_fetch`` per chunk (the retire budget the runtime gate
         asserts -- DESIGN.md §10); found counts and per-request slices all
         read the fetched host columns afterwards.
         """
-        if columns is None:
-            columns = [np.empty((total,) + c.shape[1:], c.dtype) for c in res]
-        for col, c in zip(columns, analysis_runtime.device_fetch(res)):
-            col[sl] = c
-        return columns
-
-    def _serve_stream_sharded(
-        self, op: str, a: np.ndarray, b: Optional[np.ndarray], B: int
-    ):
-        """The async double-buffered scheduler (DESIGN.md §9).
-
-        Chunk ``i+1`` is formed (sliced, converted, device_put) and
-        DISPATCHED while chunk ``i`` is still in flight; the sync point
-        trails one chunk behind dispatch, so host-side packing and result
-        unpacking overlap device compute instead of serializing on a
-        per-chunk ``block_until_ready``.  Busy seconds are the pipeline's
-        wall time (first dispatch to last retire) -- the honest serving
-        figure for an overlapped scheduler; per-chunk timings would double
-        count the overlap.  Lane/found accounting is identical to the
-        single-chip loop: padded lanes never reach results or counters.
-        """
-        columns = None
-        found = 0
-        inflight: List[Tuple[slice, int, tuple, int]] = []
-        n_chunks = 0
-        first = self.stats.chunks
-
-        def retire(r_sl: slice, r_lo: int, r_res: tuple, chunk: int):
-            nonlocal columns, found
-            with self._span("sync", chunk=chunk):
-                jax.block_until_ready(r_res)
-            with self._span("fetch", chunk=chunk):
-                columns = self._fill_columns(columns, a.size, r_sl, r_res)
-            if op == "lookup":
-                real = min(self.chunk_size, B - r_lo)
-                found += int(columns[1][r_lo : r_lo + real].sum())
-
-        lanes_per_call = self.chunk_size * (2 if op in RANGE_OPS else 1)
-        routed = self._squery.routed_slots(lanes_per_call)
         t0 = time.perf_counter()
-        for lo in range(0, a.size, self.chunk_size):
-            sl = slice(lo, lo + self.chunk_size)
-            chunk = first + n_chunks
-            self.stats.overlapped_chunks += bool(inflight)
-            with self._span("dispatch", chunk=chunk, lanes=min(self.chunk_size, B - lo)):
-                res = self._query_chunk(op, a[sl], None if b is None else b[sl])
-            self.stats.routed_slots += routed
-            inflight.append((sl, lo, res, chunk))
-            n_chunks += 1
-            if len(inflight) > 1:  # depth-2 pipeline: retire the older chunk
-                retire(*inflight.pop(0))
-        for flying in inflight:
-            retire(*flying)
-        dt = time.perf_counter() - t0
-        lanes = B * (2 if op in RANGE_OPS else 1)
-        self.stats.busy_s += dt
-        self.stats.chunks += n_chunks
-        self.stats.lanes += lanes
-        self.stats.found += found
-        self.stats.served += B
-        ops = self.stats.op(op)
-        ops.busy_s += dt
-        ops.chunks += n_chunks
-        ops.lanes += lanes
-        ops.served += B
-        return [col[:B] for col in columns]
+        with self._span("sync", chunk=chunk):
+            jax.block_until_ready(res)
+        with self._span("fetch", chunk=chunk):
+            if columns is None:
+                columns = [np.empty((total,) + c.shape[1:], c.dtype) for c in res]
+            for col, c in zip(columns, analysis_runtime.device_fetch(res)):
+                col[sl] = c
+        return columns, time.perf_counter() - t0
 
     # ------------------------------------------------------------ convenience
     def lookup(self, request_keys) -> Tuple[np.ndarray, np.ndarray]:

@@ -486,7 +486,7 @@ def test_sharded_server_soak_mixed_accounting(multi_device_host):
     submitted op counts: one lane per point/write/delete key, two per
     range request, busy seconds partitioning into the per-op
     attributions; and the phase counters must count every drain and hold
-    the sharded scheduler's spans inside the read busy time."""
+    the chunk loop's spans inside the read busy time."""
     multi_device_host("""
         from repro.core import distributed as D
         from repro.core.engine import EngineConfig
@@ -566,7 +566,7 @@ def test_sharded_server_soak_mixed_accounting(multi_device_host):
                 if op in ("write", "delete")
             )
             assert abs(read_busy + write_busy - s.busy_s) < 1e-6, mix
-            # the read spans lie inside the pipelines' busy walls, the
+            # the engine calls' spans lie inside the read busy time, the
             # ingest span is the write spans' busy time less compaction
             p = s.phase_s
             assert p["dispatch"] + p["sync"] + p["fetch"] <= read_busy, mix

@@ -116,7 +116,7 @@ def test_per_op_busy_attribution_by_lanes():
     assert s.lanes == 220
     assert sum(o.busy_s for o in s.per_op.values()) == pytest.approx(s.busy_s)
     # one drain of one read span: the engine calls' dispatch and sync
-    # spans are the busy time, and every span lies inside the drain
+    # spans lie inside the busy time, and every span inside the drain
     assert s.drains == 1
     assert set(s.phase_s) == {"drain", "pack", "dispatch", "sync", "fetch", "unpack"}
     assert s.phase_s["dispatch"] + s.phase_s["sync"] <= s.busy_s
@@ -150,19 +150,14 @@ def test_swap_applies_to_pending_requests():
 READ_SPANS = {"drain", "pack", "dispatch", "sync", "fetch", "unpack"}
 
 
-def test_drain_records_phase_spans(monkeypatch):
-    """A read drain is split into its phase spans: together they are at
-    most the drain's wall time, and the engine calls' dispatch and sync
-    spans are its busy time.  The server's clock is a fake one that moves
-    1 µs per reading and 1 ms in each engine call and each wait, so the
-    comparison does not hang on how busy the test machine is."""
+def _fake_engine_clock(monkeypatch, srv):
+    """Give the server a fake clock that moves 1 µs per reading and 1 ms in
+    each engine call, each wait and each fetch, so that timing comparisons
+    do not hang on how busy the test machine is; returns the clock."""
     import jax
 
     from repro.serving import bst_server
 
-    keys, values = make_tree_data(4095, seed=3)
-    srv = BSTServer(keys, values, EngineConfig(strategy="hrz"), chunk_size=2048)
-    srv.warmup()
     now = [0.0]
 
     def clock():
@@ -178,7 +173,21 @@ def test_drain_records_phase_spans(monkeypatch):
     monkeypatch.setattr(bst_server, "time", types.SimpleNamespace(perf_counter=clock))
     monkeypatch.setattr(bst_server, "jax", types.SimpleNamespace(
         profiler=jax.profiler, block_until_ready=slow(jax.block_until_ready)))
+    monkeypatch.setattr(bst_server, "analysis_runtime", types.SimpleNamespace(
+        device_fetch=slow(bst_server.analysis_runtime.device_fetch)))
     monkeypatch.setattr(srv, "_query_chunk", slow(srv._query_chunk))
+    return clock
+
+
+def test_drain_records_phase_spans(monkeypatch):
+    """A read drain is split into its phase spans: together they are at
+    most the drain's wall time, and the engine calls' dispatch, sync and
+    fetch spans are its busy time (on the fake clock of
+    ``_fake_engine_clock``)."""
+    keys, values = make_tree_data(4095, seed=3)
+    srv = BSTServer(keys, values, EngineConfig(strategy="hrz"), chunk_size=2048)
+    srv.warmup()
+    clock = _fake_engine_clock(monkeypatch, srv)
     rng = np.random.default_rng(4)
     for n in (1, 2047, 3000):  # two full chunks and a padded one
         srv.submit(rng.choice(keys, n).astype(np.int32))
@@ -188,9 +197,33 @@ def test_drain_records_phase_spans(monkeypatch):
     s = srv.stats
     assert set(s.phase_s) == READ_SPANS
     assert sum(s.phase_s.values()) <= wall
-    engine = s.phase_s["dispatch"] + s.phase_s["sync"]
+    engine = s.phase_s["dispatch"] + s.phase_s["sync"] + s.phase_s["fetch"]
     assert s.chunks == 3 and s.busy_s >= 6e-3
     assert 0.9 * s.busy_s <= engine <= s.busy_s, (engine, s.busy_s)
+
+
+def test_one_chunk_loop_overlaps_chunks_on_one_chip(monkeypatch):
+    """One drain of three chunks (two full, one padded) on one chip runs
+    the depth-2 loop: it answers exactly as ``lookup`` does chunk by
+    chunk, dispatches two chunks while an earlier one is in flight, routes
+    nothing, and its busy time is the dispatch, sync and fetch spans'."""
+    keys, values = make_tree_data(4095, seed=8)
+    chunk = 1024
+    srv = BSTServer(keys, values, EngineConfig(strategy="hrz"), chunk_size=chunk)
+    alone = BSTServer(keys, values, EngineConfig(strategy="hrz"), chunk_size=chunk)
+    srv.warmup()
+    q = np.random.default_rng(9).choice(np.concatenate([keys, keys + 1]),
+                                        2 * chunk + 300).astype(np.int32)
+    expect = [alone.lookup(q[lo:lo + chunk]) for lo in range(0, q.size, chunk)]
+    _fake_engine_clock(monkeypatch, srv)
+    v, f = srv.lookup(q)
+    np.testing.assert_array_equal(v, np.concatenate([e[0] for e in expect]))
+    np.testing.assert_array_equal(f, np.concatenate([e[1] for e in expect]))
+    s = srv.stats
+    assert (s.chunks, s.overlapped_chunks, s.routed_slots) == (3, 2, 0)
+    assert s.found == int(f.sum()) and s.served == s.lanes == q.size
+    engine = s.phase_s["dispatch"] + s.phase_s["sync"] + s.phase_s["fetch"]
+    assert engine <= s.busy_s <= 1.05 * engine, (engine, s.busy_s)
 
 
 def test_queue_wait_and_drains_count_once_per_drain(monkeypatch):
@@ -238,9 +271,9 @@ def test_write_span_through_a_compaction_records_ingest_compact_rewarm():
 
 
 def test_sharded_server_records_the_same_spans(multi_device_host):
-    """The double-buffered scheduler over four forced host devices records
-    the single-chip loop's span names, write path included, and its own
-    sharded put of each chunk (``shard_put``)."""
+    """The chunk loop over four forced host devices records the span names
+    it records on one chip, write path included, and its own sharded put
+    of each chunk (``shard_put``)."""
     out = multi_device_host("""
         from repro.core import distributed as D
         from repro.core.engine import EngineConfig
@@ -374,7 +407,7 @@ def test_columnar_drain_answers_as_each_request_alone(shape, strategy):
 
 
 def test_sharded_columnar_drain_answers_as_each_request_alone(multi_device_host):
-    """The same check through the double-buffered sharded scheduler."""
+    """The same check through the sharded server."""
     out = multi_device_host(f"""
         sys.path.insert(0, {os.path.dirname(os.path.abspath(__file__))!r})
         from repro.core import distributed as D
